@@ -140,14 +140,20 @@ def smoke(backend: str = "analytical") -> int:
 
 
 def record() -> int:
-    """Re-measure the fleet kernel recording (interpret mode) by driving
-    the exact session the replay backend reproduces."""
+    """Re-measure the fleet kernel recording by driving the exact
+    session the replay backend reproduces: the interpreter on a CPU
+    host, the compiled kernels on a TPU host, each device kind into its
+    own file."""
     from repro.apps.fleet import fleet_pallas_oracle
+    from repro.core.pallas_oracle import platform_interpret
     from repro.core.registry import build_session
-    oracle = fleet_pallas_oracle("record")
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+    oracle = fleet_pallas_oracle("record", interpret=platform_interpret())
     res = build_session("fleet", "pallas", tool=oracle, workers=1).run()
     saved = oracle.flush()
-    print(f"fleet-record: {len(oracle.store)} measured points -> {saved} "
+    print(f"fleet-record: {len(oracle.store)} measured points "
+          f"(device_kind={oracle.device_kind!r}) -> {saved} "
           f"({res.total_invocations} oracle invocations)")
     return 0
 
@@ -160,7 +166,8 @@ if __name__ == "__main__":
     ap.add_argument("--smoke", action="store_true",
                     help="front-vs-exhaustive + invocation-frugality gate")
     ap.add_argument("--record", action="store_true",
-                    help="re-measure the interpret-mode kernel recording")
+                    help="re-measure this platform's kernel recording "
+                         "(interpreter on CPU, compiled on TPU)")
     ap.add_argument("--backend", choices=["analytical", "pallas"],
                     default="analytical")
     args = ap.parse_args()

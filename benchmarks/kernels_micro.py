@@ -6,12 +6,13 @@ is the work list: a new app's kernels join by registering):
   * ``analytical`` — the same cases timed down their XLA reference
     path (``use_pallas=False``).  CPU microseconds, reported only to
     catch regressions in the jnp fallback kernels.
-  * ``pallas`` — every kernel runs through its Pallas path in
-    interpret mode and is checked against its jnp oracle; the reported
-    numbers are interpret-mode walls (structural, not TPU performance)
-    plus the parity error.  ``--smoke`` shrinks the tile and exits
-    non-zero on any parity failure — the CI gate that the measured
-    backend's kernels still compute the right thing.
+  * ``pallas`` — every kernel runs through its Pallas path and is
+    checked against its jnp oracle: compiled on a TPU, in interpret
+    mode on a CPU (interpret-mode walls are structural, not TPU
+    performance).  Every row names the platform it ran on.  ``--smoke``
+    shrinks the tile and exits non-zero on any parity failure — the CI
+    gate that the measured backend's kernels still compute the right
+    thing.
 
 Standalone (all apps at once):
 
@@ -26,8 +27,9 @@ import jax
 import jax.numpy as jnp
 
 # every registered app joins both cells through its parity cases: the
-# pallas cell checks + times the kernels in interpret mode, the
-# analytical cell times the same cases down their XLA reference path
+# pallas cell checks + times the kernels (compiled on a TPU, interpreted
+# on a CPU), the analytical cell times the same cases down their XLA
+# reference path
 SCENARIOS = {"apps": "*", "backends": ("analytical", "pallas")}
 
 
@@ -76,17 +78,24 @@ def _registry_parity_cases(tile: int, app: str | None = None):
 def run_pallas(report, *, app: str | None = None, tile: int = 128,
                ports: int = 4, unrolls: int = 8,
                reps: int = 3, tol: float = 1e-4) -> int:
-    """Interpret-mode drive of the registered Pallas kernels (every
-    app's, or one app's cell) vs their jnp oracles.  Returns the number
-    of parity failures."""
+    """Drive the registered Pallas kernels (every app's, or one app's
+    cell) vs their jnp oracles — compiled on a TPU, interpreted on a
+    CPU.  Returns the number of parity failures.  The oracles' matmuls
+    run at full f32 precision, as the kernels' do, so one tolerance
+    holds on both platforms."""
+    from repro.core.pallas_oracle import platform_interpret
+    interpret = platform_interpret()
+    dev = jax.devices()[0]
+    platform = "interpret" if interpret else dev.platform
     lines = [f"# Pallas kernels ({app or 'all registered apps'}), "
-             f"interpret mode, "
+             f"{'interpret mode' if interpret else 'compiled'} on "
+             f"{dev.platform} ({dev.device_kind}), "
              f"tile={tile}, ports={ports}, unrolls={unrolls}",
-             "kernel,us_per_call_interpret,max_rel_err"]
+             "kernel,platform,us_per_call,max_rel_err"]
     failures = 0
     for name, fn, oracle, args in _registry_parity_cases(tile, app):
         got = fn(*args, ports=ports, unrolls=unrolls, use_pallas=True,
-                 interpret=True)
+                 interpret=interpret)
         want = oracle(*args)
         errs = [_max_err(g, w) for g, w in
                 zip(got if isinstance(got, tuple) else (got,),
@@ -95,10 +104,11 @@ def run_pallas(report, *, app: str | None = None, tile: int = 128,
         if err > tol:
             failures += 1
         us = _time(fn, *args, reps=reps, ports=ports, unrolls=unrolls,
-                   use_pallas=True, interpret=True)
-        lines.append(f"{name},{us:.0f},{err:.2e}")
+                   use_pallas=True, interpret=interpret)
+        lines.append(f"{name},{platform},{us:.0f},{err:.2e}")
         report.csv(f"{name}_pallas", us,
-                   f"parity={'OK' if err <= tol else 'FAIL'}_{err:.1e}")
+                   f"{platform}_parity={'OK' if err <= tol else 'FAIL'}"
+                   f"_{err:.1e}")
     report.write("kernels_micro_pallas", lines)
     return failures
 

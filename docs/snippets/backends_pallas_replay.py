@@ -15,8 +15,9 @@ def main():
     for point in result.pareto():
         print(f"  theta {point.perf:8.2f}  cost {point.cost:12.1f}")
 
-    # explicit-oracle form, e.g. to re-record on new hardware:
-    oracle = wami_pallas_oracle("record")
+    # explicit-oracle form, e.g. to re-record on new hardware (compiled
+    # on a TPU; pass interpret=True to time the interpreter on a CPU):
+    oracle = wami_pallas_oracle("record", interpret=True)
     session = wami_pallas_session(delta=0.25, oracle=oracle)
     session.run()
     print("recording written to", oracle.flush())

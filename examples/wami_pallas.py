@@ -1,7 +1,7 @@
 """COSMOS over the *measured* backend: the WAMI DSE driven by a
 PallasOracle that prices each (component, knob) point by compiling and
 timing the stage's knob-parameterized Pallas kernel (interpret mode on
-CPU, the real grid on TPU).
+CPU, the compiled kernel on TPU).
 
 Default run replays the recording checked in under
 ``artifacts/measurements/`` — fully deterministic, no TPU needed — then
@@ -10,6 +10,10 @@ and reports both backends' Pareto views side by side.
 
     PYTHONPATH=src python examples/wami_pallas.py            # replay
     PYTHONPATH=src python examples/wami_pallas.py --record   # re-measure
+
+``--record`` times the interpreter on a CPU host and the compiled
+kernels on a TPU host; the recording's ``device_kind`` says which, and
+each device kind writes its own file under ``artifacts/measurements/``.
 """
 
 import argparse
@@ -24,7 +28,7 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--record", action="store_true",
                     help="re-measure every point this drive touches and "
-                         "rewrite the measurement recording")
+                         "rewrite this platform's measurement recording")
     ap.add_argument("--tile", type=int, default=None,
                     help="PLM tile edge (default: the WAMI 128)")
     ap.add_argument("--delta", type=float, default=0.25)
@@ -36,10 +40,17 @@ def main():
                                         wami_pallas_session)
     from repro.core import ExplorationSession, calibrate_to_records
     from repro.core.calibrate import CalibratedTool
+    from repro.core.pallas_oracle import platform_interpret
+    from repro.launch.compile_cache import enable_compile_cache
 
     tile = args.tile or TILE
     mode = "record" if args.record else "replay"
-    oracle = wami_pallas_oracle(mode, tile=tile)
+    if args.record:
+        enable_compile_cache()
+        oracle = wami_pallas_oracle(mode, tile=tile,
+                                    interpret=platform_interpret())
+    else:
+        oracle = wami_pallas_oracle(mode, tile=tile)
     t0 = time.time()
     session = wami_pallas_session(args.delta, oracle=oracle,
                                   workers=1 if args.record else 8)
@@ -51,7 +62,8 @@ def main():
           f"invocations, {len(res.mapped)} mapped points, {wall:.1f}s")
     if saved:
         print(f"[pallas] recording saved: {saved} "
-              f"({len(oracle.store)} measured points)")
+              f"({len(oracle.store)} measured points, device_kind="
+              f"{oracle.device_kind!r})")
     by_phase = session.ledger.records_by_phase()
     print("[pallas] invocations by phase: "
           + ", ".join(f"{k}={v}" for k, v in sorted(by_phase.items())))

@@ -18,7 +18,8 @@ by BOTH oracle families:
     scan) and ``unrolls`` onto the sequential block depth (KV rows /
     chunk length per grid step) — the same lane-bank reading DESIGN.md
     §2 gives the WAMI kernels.  Interpret-mode walls are recorded under
-    ``artifacts/measurements/`` and the XLA roofline's constants are
+    ``artifacts/measurements/`` (a chip's walls go to a file of their
+    own per device kind) and the XLA roofline's constants are
     fitted to them through :mod:`repro.core.calibrate`
     (:func:`fleet_calibrated_tool`), so the analytical fallback prices
     on the measured axes.
@@ -31,8 +32,9 @@ one VMEM pool, exactly the cross-component sharing WAMI's LK loop gets.
 
 from __future__ import annotations
 
+import functools
 import os
-from typing import Callable, Dict, Optional, Sequence
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -41,7 +43,8 @@ from ...configs import SHAPES, get_config
 from ...core.knobs import KnobSpace
 from ...core.pallas_oracle import (MeasurementSet, MeasurementStore,
                                    PallasKernelSpec, PallasOracle,
-                                   open_recording)
+                                   live_device_kind, open_recording,
+                                   recording_file)
 from ...core.plm.planner import PLMPlanner
 from ...core.plm.units import UnitSystem, fit_unit_system
 from ...core.registry import App, build_session, register_app
@@ -79,11 +82,13 @@ _FLEET_STAGES = {
 }
 
 
-def default_measurement_path(tile: int = 0) -> str:
-    """One recording file for the fleet kernels (no tile axis: the
-    kernel geometry is fixed, so everything keys under tile 0)."""
+def default_measurement_path(tile: int = 0,
+                             device_kind: str = "interpret") -> str:
+    """One recording file per device kind for the fleet kernels (no
+    tile axis: the kernel geometry is fixed, so everything keys under
+    tile 0)."""
     return os.path.join(_REPO_ROOT, "artifacts", "measurements",
-                        "fleet_pallas.json")
+                        recording_file("fleet_pallas", device_kind))
 
 
 # ----------------------------------------------------------------------
@@ -169,19 +174,16 @@ def fleet_kernel_specs(tile: int = 0) -> Dict[str, PallasKernelSpec]:
     q, k, v, x, dt, A, Bm, Cm = _fleet_inputs()
 
     def build_flash(ports: int, unrolls: int, interpret: bool):
-        def run():
-            return mha(q, k, v, causal=True,
-                       block_q=FLASH_S // ports,
-                       block_kv=_flash_block_kv(unrolls),
-                       use_pallas=True, interpret=interpret)
-        return run
+        return jax.jit(functools.partial(
+            mha, causal=True, block_q=FLASH_S // ports,
+            block_kv=_flash_block_kv(unrolls), use_pallas=True,
+            interpret=interpret)), (q, k, v)
 
     def build_ssd(ports: int, unrolls: int, interpret: bool):
-        def run():
-            return ssd(x[:, :, :ports, :], dt[:, :, :ports], A[:ports],
-                       Bm, Cm, chunk=_ssd_chunk(unrolls),
-                       use_pallas=True, interpret=interpret)
-        return run
+        return jax.jit(functools.partial(
+            ssd, chunk=_ssd_chunk(unrolls), use_pallas=True,
+            interpret=interpret)), (x[:, :, :ports, :], dt[:, :, :ports],
+                                    A[:ports], Bm, Cm)
 
     return {
         "flash_attention": PallasKernelSpec(
@@ -198,19 +200,22 @@ def fleet_kernel_specs(tile: int = 0) -> Dict[str, PallasKernelSpec]:
 def fleet_parity_cases(tile: int = FLASH_S):
     """(name, knobbed_fn, oracle_fn, args) for the parity gate: the
     fleet kernels behind the same (ports, unrolls) calling convention
-    the WAMI cases use.  ``tile`` scales the token count (smoke runs
-    shrink it)."""
+    the WAMI cases use.  ``tile`` is the attention token count; the scan
+    runs SSD_S / FLASH_S times longer, so the default checks both
+    kernels at their measured geometry (smoke runs shrink it)."""
     S = max(32, tile)
+    S_ssd = S * SSD_S // FLASH_S
     key = jax.random.PRNGKey(11)
     ks = jax.random.split(key, 8)
     q = jax.random.normal(ks[0], (1, S, FLASH_HEADS, FLASH_D))
     k = jax.random.normal(ks[1], (1, S, 1, FLASH_D))
     v = jax.random.normal(ks[2], (1, S, 1, FLASH_D))
-    x = jax.random.normal(ks[3], (1, S, SSD_MAX_HEADS, SSD_P))
-    dt = jax.nn.softplus(jax.random.normal(ks[4], (1, S, SSD_MAX_HEADS)))
+    x = jax.random.normal(ks[3], (1, S_ssd, SSD_MAX_HEADS, SSD_P))
+    dt = jax.nn.softplus(jax.random.normal(ks[4],
+                                           (1, S_ssd, SSD_MAX_HEADS)))
     A = -jnp.exp(jax.random.normal(ks[5], (SSD_MAX_HEADS,)) * 0.3)
-    Bm = jax.random.normal(ks[6], (1, S, SSD_N)) * 0.3
-    Cm = jax.random.normal(ks[7], (1, S, SSD_N)) * 0.3
+    Bm = jax.random.normal(ks[6], (1, S_ssd, SSD_N)) * 0.3
+    Cm = jax.random.normal(ks[7], (1, S_ssd, SSD_N)) * 0.3
 
     def mha_knobbed(q, k, v, *, ports, unrolls, use_pallas, interpret):
         return mha(q, k, v, causal=True, block_q=max(1, S // ports),
@@ -239,16 +244,21 @@ def fleet_parity_cases(tile: int = FLASH_S):
 # ----------------------------------------------------------------------
 def fleet_pallas_oracle(mode: str = "replay", *,
                         measurements: Optional[MeasurementSet] = None,
-                        fallback=None, interpret: bool = True,
+                        fallback=None, interpret: bool = False,
                         flush_every: int = 16, missing: str = "fallback",
                         timer=None, **kwargs) -> PallasOracle:
     """The measured fleet oracle.  Default: deterministic replay of the
     checked-in interpret-mode recording with the *calibrated* XLA tool
-    as fallback — the calibrated-measured backend of ``get_app("fleet")``."""
+    as fallback — the calibrated-measured backend of ``get_app("fleet")``.
+    A live drive compiles for the TPU unless ``interpret`` is asked for,
+    and records into its device kind's own file."""
+    # a replay reads its file's device kind; a live drive its own
+    live_kind = (None if mode == "replay" else
+                 "interpret" if interpret else live_device_kind())
     if measurements is None and mode in ("record", "replay"):
-        measurements = open_recording(default_measurement_path(),
-                                      mode=mode, tile=0,
-                                      interpret=interpret,
+        kind = live_kind or "interpret"
+        measurements = open_recording(default_measurement_path(0, kind),
+                                      mode=mode, tile=0, device_kind=kind,
                                       flush_every=flush_every)
     if fallback is None:
         if mode == "replay" and missing == "fallback":
@@ -259,6 +269,7 @@ def fleet_pallas_oracle(mode: str = "replay", *,
                         measurements=measurements,
                         components_factory=fleet_kernel_specs,
                         fallback=fallback, interpret=interpret,
+                        device_kind=live_kind,
                         missing=missing if mode == "replay" else "error",
                         record_hint="re-record with `python benchmarks/"
                                     "fleet_dse.py --record`",

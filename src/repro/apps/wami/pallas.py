@@ -17,14 +17,18 @@ constructors as thin wrappers over ``build_session("wami", "pallas")``:
     the default mode replays the recordings checked in under
     ``artifacts/measurements/`` through a
     :class:`~repro.core.pallas_oracle.MeasurementSet` (regenerate:
-    ``python examples/wami_pallas.py --record [--tile N]``).
+    ``python examples/wami_pallas.py --record [--tile N]``, which times
+    the interpreter on a CPU and the compiled kernels on a TPU, each
+    device kind into its own file).
 
 Inputs are baked deterministically per tile size so that record and
-replay price the same physical workload.
+replay price the same physical workload; each knob point is one jitted
+program (ops wrapper + ``pallas_call``) over those inputs.
 """
 
 from __future__ import annotations
 
+import functools
 import os
 from typing import Callable, Dict, Optional, Sequence
 
@@ -34,7 +38,8 @@ import jax.numpy as jnp
 from ...core.hlsim import HLSTool
 from ...core.pallas_oracle import (MeasurementSet, MeasurementStore,
                                    PallasKernelSpec, PallasOracle,
-                                   open_recording)
+                                   live_device_kind, open_recording,
+                                   recording_file)
 from ...core.plm.units import UnitSystem, fit_unit_system
 from ...core.registry import App, build_session, register_app
 from ...core.session import ExplorationSession
@@ -60,9 +65,14 @@ _REPO_ROOT = os.path.abspath(
 WAMI_RECORDED_TILES = (64, 128, 256)
 
 
-def default_measurement_path(tile: int = C.TILE) -> str:
+def default_measurement_path(tile: int = C.TILE,
+                             device_kind: str = "interpret") -> str:
+    """The recording file for (tile, device kind), e.g.
+    ``wami_pallas_tile128.json`` (interpret) or
+    ``wami_pallas_tile128.tpu_v5_lite.json``."""
     return os.path.join(_REPO_ROOT, "artifacts", "measurements",
-                        f"wami_pallas_tile{tile}.json")
+                        recording_file(f"wami_pallas_tile{tile}",
+                                       device_kind))
 
 
 def wami_measurement_set(tiles: Sequence[int] = (C.TILE,),
@@ -92,10 +102,9 @@ def wami_pallas_components(tile: int = C.TILE
 
     def bake(fn: Callable, *args) -> Callable:
         def build(ports: int, unrolls: int, interpret: bool):
-            def run():
-                return fn(*args, ports=ports, unrolls=unrolls,
-                          use_pallas=True, interpret=interpret)
-            return run
+            return jax.jit(functools.partial(
+                fn, ports=ports, unrolls=unrolls, use_pallas=True,
+                interpret=interpret)), args
         return build
 
     shape = (tile, tile)
@@ -181,27 +190,36 @@ def wami_pallas_oracle(mode: str = "replay", *, tile: int = C.TILE,
                        store_path: Optional[str] = None,
                        measurements: Optional[MeasurementSet] = None,
                        fallback: Optional[HLSTool] = None,
-                       interpret: bool = True,
+                       interpret: bool = False,
                        flush_every: int = 16,
                        timer=None, **kwargs) -> PallasOracle:
     """The measured WAMI oracle.  Default: deterministic replay from the
-    checked-in recording (CI-safe, no TPU).  Record mode flushes the
-    store every ``flush_every`` timings through the atomic rename
-    protocol and resumes from whatever an interrupted campaign already
-    flushed — killed recordings never re-pay for timed points."""
+    checked-in interpret recording (CI-safe, no TPU); a replay of
+    another file reads that file's device kind.  A live drive
+    (``measure``/``record``) compiles for the TPU unless ``interpret``
+    is asked for, and records into its device kind's own file.  Record
+    mode flushes the store every ``flush_every`` timings through the
+    atomic rename protocol and resumes from whatever an interrupted
+    campaign already flushed — killed recordings never re-pay for timed
+    points."""
+    # a replay reads its file's device kind; a live drive its own
+    live_kind = (None if mode == "replay" else
+                 "interpret" if interpret else live_device_kind())
     if measurements is None and mode in ("record", "replay"):
         if store is not None:
             measurements = MeasurementSet.from_store(store, tile=tile)
         else:
+            kind = live_kind or "interpret"
             measurements = open_recording(
-                store_path or default_measurement_path(tile), mode=mode,
-                tile=tile, interpret=interpret, flush_every=flush_every)
+                store_path or default_measurement_path(tile, kind),
+                mode=mode, tile=tile, device_kind=kind,
+                flush_every=flush_every)
     return PallasOracle(wami_pallas_components(tile), mode=mode,
                         measurements=measurements,
                         components_factory=wami_pallas_components,
                         fallback=fallback or wami_hls_tool(),
-                        interpret=interpret, timer=timer,
-                        native_tile=tile,
+                        interpret=interpret, device_kind=live_kind,
+                        timer=timer, native_tile=tile,
                         record_hint=f"re-record with `python examples/"
                                     f"wami_pallas.py --record --tile {tile}`",
                         **kwargs)

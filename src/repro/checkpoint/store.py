@@ -23,15 +23,14 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
-from ..utils import keystr_path
-
 __all__ = ["save", "restore", "latest_step", "list_steps"]
 
 
 def _leaves_with_paths(tree: Any) -> List[Tuple[str, Any]]:
     out = []
     for kp, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
-        out.append((keystr_path(kp), leaf))
+        out.append((jax.tree_util.keystr(kp, simple=True, separator="/"),
+                    leaf))
     return out
 
 
@@ -113,7 +112,7 @@ def restore(root: str, step: int, like: Any) -> Tuple[Any, Dict]:
     flat, treedef = jax.tree_util.tree_flatten_with_path(like)
     leaves = []
     for kp, leaf in flat:
-        path = keystr_path(kp)
+        path = jax.tree_util.keystr(kp, simple=True, separator="/")
         m = by_path.get(path)
         if m is None:
             raise KeyError(f"checkpoint missing leaf {path}")

@@ -177,7 +177,8 @@ def _lint_measurement_json(app_name: str, tile: int, path: str,
 def _lint_kernel_specs(app, findings: List[LintFinding]) -> None:
     if app.kernel_specs is None:
         return
-    from ..pallas_oracle import _VMEM_BUDGET
+    from ..pallas_oracle import VMEM_BUDGETS
+    budget = min(VMEM_BUDGETS.values())    # the tightest device kind
     try:
         specs = app.kernel_specs(app.native_tile)
     except Exception as e:            # noqa: BLE001
@@ -224,7 +225,7 @@ def _lint_kernel_specs(app, findings: List[LintFinding]) -> None:
                         f"(p={ports}, u={unrolls}): vmem={step}, "
                         f"grid={grid}"))
                     continue
-                if 2 * step <= _VMEM_BUDGET:
+                if 2 * step <= budget:
                     fits_vmem = True
         if not feasible:
             findings.append(LintFinding(
@@ -235,7 +236,7 @@ def _lint_kernel_specs(app, findings: List[LintFinding]) -> None:
             findings.append(LintFinding(
                 "SPEC003", app.name, comp,
                 f"no divisible knob point fits the double-buffered "
-                f"VMEM budget ({_VMEM_BUDGET} bytes)"))
+                f"VMEM budget ({budget} bytes)"))
 
 
 # ----------------------------------------------------------------------

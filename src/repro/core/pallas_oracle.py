@@ -7,15 +7,19 @@ from running the thing — each (component, knob) point compiles the
 component's knob-parameterized Pallas kernel and *times* it
 (docs/backends.md walks through the protocol):
 
-  * latency lambda — measured wall clock per kernel launch, divided by
-    ``ports``: the grid columns are parallel lane-banks (DESIGN.md §2),
-    so the per-bank effective latency is what the TMG composes;
+  * latency lambda — measured wall clock per launch of the point's
+    jitted program (ops wrapper + ``pallas_call``, compiled and warmed
+    before the timed reps), divided by ``ports``: the grid columns are
+    parallel lane-banks (DESIGN.md §2), so the per-bank effective
+    latency is what the TMG composes;
   * area alpha — the VMEM footprint: the double-buffered working set
     summed over the ``ports`` banks, plus a fixed per-bank pipeline
     overhead (the TPU shadow of Mnemosyne's bank-controller area);
   * the lambda-constraint — a knob point is infeasible when the grid
-    does not divide (W % ports, H % unrolls) or the double-buffered
-    block no longer fits the VMEM budget, and, like every backend, when
+    does not divide (W % ports, H % unrolls), when the double-buffered
+    block no longer fits the device kind's VMEM budget, when the TPU
+    compiler refuses the kernel (a failed synthesis, tagged
+    ``detail["refused"]``), and, like every backend, when
     ``max_states`` caps the Eq. (1) state estimate.
 
 Measurements are memoized per (component, ports, unrolls, tile) — one
@@ -32,12 +36,19 @@ machine-free (CI has no TPU; the checked-in recordings under
 ``artifacts/measurements/`` drive the same fronts byte-for-byte).
 Components without a Pallas kernel fall back to a wrapped analytical
 tool, so a mixed system (the full WAMI TMG) still explores end-to-end.
+
+A live measurement (``measure``/``record``) runs where the caller says:
+``interpret=True`` times the Pallas interpreter (device kind
+``"interpret"``), ``interpret=False`` compiles for the TPU and tags the
+walls with ``jax.devices()[0].device_kind`` — and raises when JAX finds
+no TPU, never timing the CPU in its place.
 """
 
 from __future__ import annotations
 
 import json
 import os
+import re
 import threading
 import time
 import warnings
@@ -54,6 +65,11 @@ __all__ = [
     "MissingMeasurementError",
     "PallasOracle",
     "open_recording",
+    "VMEM_BUDGETS",
+    "device_vmem_budget",
+    "live_device_kind",
+    "platform_interpret",
+    "recording_file",
 ]
 
 # one physical measurement inside one store: (component, ports, unrolls).
@@ -67,15 +83,72 @@ MeasureKey = Tuple[str, int, int]
 # component's native tile, device_kind "interpret" = CPU interpret mode
 SetKey = Tuple[int, str]
 
-_VMEM_BUDGET = 16 * 1024 * 1024     # bytes per TPU core
+# VMEM a kernel's double-buffered blocks may claim, per device kind.
+# TPU v5e ("TPU v5 lite"): Mosaic's default scoped-VMEM limit, 16 MiB of
+# the core's 128 MiB.  "interpret" keeps the same budget, so interpret
+# recordings price exactly as they always have.  A kind missing here is
+# an error, not a default: its budget has to be looked up and added.
+VMEM_BUDGETS: Dict[str, int] = {
+    "interpret": 16 * 1024 * 1024,
+    "TPU v5 lite": 16 * 1024 * 1024,
+}
+
+
+def device_vmem_budget(device_kind: str) -> int:
+    """The VMEM budget (bytes) of ``device_kind``; unknown kinds raise."""
+    try:
+        return VMEM_BUDGETS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no VMEM budget for device kind {device_kind!r}; known kinds: "
+            f"{sorted(VMEM_BUDGETS)} (add it to VMEM_BUDGETS in "
+            f"repro/core/pallas_oracle.py)") from None
+
+
+def live_device_kind() -> str:
+    """``jax.devices()[0].device_kind`` of the TPU a compiled (non-
+    interpret) measurement runs on; raises when JAX finds no TPU."""
+    import jax
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        raise RuntimeError(
+            f"a live Pallas measurement with interpret=False needs a TPU, "
+            f"but JAX found platform {dev.platform!r} "
+            f"({dev.device_kind!r}); pass interpret=True to time the "
+            f"Pallas interpreter, or replay a recording")
+    return str(dev.device_kind)
+
+
+def platform_interpret() -> bool:
+    """How the Pallas TPU kernels run on this host: compiled on a TPU
+    (False), interpreted on a CPU (True); any other platform raises."""
+    import jax
+    platform = jax.devices()[0].platform
+    if platform not in ("tpu", "cpu"):
+        raise RuntimeError(f"Pallas TPU kernels run compiled on a TPU or "
+                           f"interpreted on a CPU; JAX found {platform!r}")
+    return platform == "cpu"
+
+
+def recording_file(stem: str, device_kind: str) -> str:
+    """The recording file name of ``stem`` for ``device_kind``: the
+    interpret recordings keep ``<stem>.json``; a chip's walls go to a
+    file of their own, e.g. ``<stem>.tpu_v5_lite.json``."""
+    if device_kind == "interpret":
+        return f"{stem}.json"
+    slug = re.sub(r"[^a-z0-9]+", "_", device_kind.lower()).strip("_")
+    return f"{stem}.{slug}.json"
 
 
 @dataclass(frozen=True)
 class PallasKernelSpec:
     """One knob-parameterized kernel, as the oracle sees it.
 
-    ``build(ports, unrolls, interpret)`` returns a zero-argument runner
-    (inputs baked in, deterministic) whose launch the oracle times.
+    ``build(ports, unrolls, interpret)`` returns ``(program, args)``: a
+    ``jax.jit``-compiled program covering the ops wrapper and the
+    ``pallas_call``, and its baked deterministic inputs.  The oracle
+    lowers and compiles ``program`` for ``args`` apart from the timed
+    reps, then times ``compiled(*args)`` launches.
     ``vmem_bytes``/``grid_steps`` are the kernel package's cost models
     (``(H, W, ports=, unrolls=) -> int``).  ``n_in``/``n_out`` are the
     VMEM blocks the kernel streams per grid step — the Eq. (1)
@@ -84,7 +157,7 @@ class PallasKernelSpec:
 
     name: str
     shape: Tuple[int, int]                      # (H, W) the stage processes
-    build: Callable[[int, int, bool], Callable[[], Any]]
+    build: Callable[[int, int, bool], Tuple[Any, Tuple[Any, ...]]]
     vmem_bytes: Callable[..., int]
     grid_steps: Callable[..., int]
     n_in: int
@@ -113,7 +186,9 @@ class MeasurementStore:
     launch.  The derived quantities (per-bank lambda, VMEM area,
     feasibility) are recomputed by the oracle on replay, so a recording
     survives cost-model refinements.  ``save`` writes sorted keys —
-    re-recording an identical machine state diffs clean.
+    re-recording an identical machine state diffs clean.  Points the
+    TPU compiler refused are kept apart in ``refused`` (key -> reason),
+    so a replay reports the same failed syntheses the recording saw.
 
     ``flush_every`` > 0 makes the store durable *incrementally*: every
     N-th ``put`` rewrites the file through the same atomic
@@ -130,6 +205,7 @@ class MeasurementStore:
         self.path = path
         self.meta: Dict[str, Any] = dict(meta or {})
         self.entries: Dict[MeasureKey, float] = {}
+        self.refused: Dict[MeasureKey, str] = {}
         self.flush_every = max(0, int(flush_every))
         self._dirty = 0
         self._save_lock = threading.Lock()
@@ -144,8 +220,9 @@ class MeasurementStore:
         store = cls(path=path, meta=doc.get("meta", {}),
                     flush_every=flush_every)
         for k, wall_s in doc["entries"].items():
-            comp, p, u = k.rsplit(":", 2)
-            store.entries[(comp, int(p[1:]), int(u[1:]))] = float(wall_s)
+            store.entries[cls._parse_key(k)] = float(wall_s)
+        for k, reason in doc.get("refused", {}).items():
+            store.refused[cls._parse_key(k)] = str(reason)
         return store
 
     @property
@@ -156,31 +233,47 @@ class MeasurementStore:
     @property
     def device_kind(self) -> str:
         """Where the walls came from: ``"interpret"`` (CPU interpret
-        mode) or the real device platform the recording tags."""
+        mode) or the chip's ``device_kind`` the recording tags."""
         kind = self.meta.get("device_kind")
         if kind:
             return str(kind)
-        return "interpret" if self.meta.get("interpret", True) else "device"
+        if self.meta.get("interpret", True):
+            return "interpret"
+        raise ValueError(f"recording {self.path!r} was made off the "
+                         f"interpreter but tags no device_kind")
 
     @staticmethod
     def _key_str(key: MeasureKey) -> str:
         comp, ports, unrolls = key
         return f"{comp}:p{ports}:u{unrolls}"
 
+    @staticmethod
+    def _parse_key(k: str) -> MeasureKey:
+        comp, p, u = k.rsplit(":", 2)
+        return comp, int(p[1:]), int(u[1:])
+
     def get(self, key: MeasureKey) -> Optional[float]:
         return self.entries.get(key)
 
     def put(self, key: MeasureKey, wall_s: float) -> None:
+        self._write(self.entries, key, float(wall_s))
+
+    def refuse(self, key: MeasureKey, reason: str) -> None:
+        """Record that the compiler refused ``key``."""
+        self._write(self.refused, key, reason)
+
+    def _write(self, table: Dict[MeasureKey, Any], key: MeasureKey,
+               value: Any) -> None:
         if self.flush_every:
             # the write happens under the save lock so a concurrent
             # autoflush never iterates a mutating dict
             with self._save_lock:
-                self.entries[key] = float(wall_s)
+                table[key] = value
                 self._dirty += 1
                 if self._dirty >= self.flush_every and self.path:
                     self._save_locked(self.path)
         else:
-            self.entries[key] = float(wall_s)
+            table[key] = value
 
     def save(self, path: Optional[str] = None) -> str:
         path = path or self.path
@@ -193,6 +286,9 @@ class MeasurementStore:
         doc = {"version": 1, "meta": self.meta,
                "entries": {self._key_str(k): self.entries[k]
                            for k in sorted(self.entries)}}
+        if self.refused:
+            doc["refused"] = {self._key_str(k): self.refused[k]
+                              for k in sorted(self.refused)}
         os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
         tmp = path + ".tmp"
         with open(tmp, "w") as f:
@@ -299,6 +395,18 @@ class MeasurementSet:
         return ", ".join(f"(tile={t}, device={k!r})" for t, k in self.keys()) \
             or "<empty>"
 
+    def replay_kind(self) -> str:
+        """The device kind a replay reads by default: the set's only
+        kind, else ``"interpret"`` (the committed recordings) when the
+        set holds it; a set of several chip kinds must be told which."""
+        kinds = sorted({k for _, k in self._stores})
+        if len(kinds) == 1:
+            return kinds[0]
+        if "interpret" in kinds or not kinds:
+            return "interpret"
+        raise ValueError(f"MeasurementSet holds recordings of several "
+                         f"device kinds {kinds}; pass device_kind=")
+
     def __contains__(self, key: SetKey) -> bool:
         return (int(key[0]), key[1]) in self._stores
 
@@ -329,13 +437,25 @@ class PallasOracle(OracleBatchMixin):
     deterministic one to make a *fresh* drive byte-comparable to a
     replayed one.
 
+    ``interpret`` says how a live (``measure``/``record``) drive runs the
+    kernels: ``True`` times the Pallas interpreter, ``False`` (the
+    default) compiles them for the TPU and raises when there is none.
+    ``device_kind`` keys the recordings the oracle reads and writes; it
+    defaults to ``"interpret"`` or the TPU's ``device_kind`` for a live
+    drive, and to :meth:`MeasurementSet.replay_kind` for a replay.  The
+    VMEM budget comes from :data:`VMEM_BUDGETS` for that kind unless
+    ``vmem_budget`` overrides it.
+
     ``native_tile`` declares the tile the ``components`` kernel specs
     were built at; a request's tile resolves to it when unset (tile 0).
     A resolved tile with a recording in ``measurements`` replays (or
-    records) measured walls; any other tile is routed to the fallback
+    records) measured walls.  In replay and measure mode, a tile without
+    a recording (or without kernel specs) is routed to the fallback
     tool, which re-prices the component at that tile analytically (pair
     with a unit-calibrated fallback, :mod:`repro.core.plm.units`, to
-    keep the axes comparable).  ``components_factory(tile)`` — when
+    keep the axes comparable); in record mode a kernel component with no
+    store for the oracle's key raises instead — a recording never mixes
+    in analytical prices.  ``components_factory(tile)`` — when
     given — rebuilds the kernel specs at a measured non-native tile so
     multi-tile recordings price with the right geometry.
 
@@ -356,8 +476,8 @@ class PallasOracle(OracleBatchMixin):
                  components_factory: Optional[
                      Callable[[int], Dict[str, PallasKernelSpec]]] = None,
                  fallback: Optional[SynthesisTool] = None,
-                 interpret: bool = True,
-                 vmem_budget: int = _VMEM_BUDGET,
+                 interpret: bool = False,
+                 vmem_budget: Optional[int] = None,
                  bank_overhead_bytes: int = 4096,
                  reps: int = 3,
                  native_tile: int = 0,
@@ -374,9 +494,6 @@ class PallasOracle(OracleBatchMixin):
         if store is not None and measurements is not None:
             raise ValueError("pass either store= (legacy, one recording) "
                              "or measurements= (MeasurementSet), not both")
-        self.interpret = interpret
-        self.device_kind = device_kind or (
-            "interpret" if interpret else _default_device_kind())
         if store is not None:
             warnings.warn(
                 "PallasOracle(store=...) is the legacy single-recording "
@@ -384,17 +501,23 @@ class PallasOracle(OracleBatchMixin):
                 "(or build a multi-tile set) instead",
                 DeprecationWarning, stacklevel=2)
             measurements = MeasurementSet.from_store(
-                store, tile=native_tile or None,
-                device_kind=self.device_kind)
+                store, tile=native_tile or None)
         if mode in ("record", "replay") and (measurements is None
                                              or len(measurements) == 0):
             raise ValueError(f"mode={mode!r} requires a MeasurementStore "
                              f"or a non-empty MeasurementSet")
+        self.interpret = interpret
+        if device_kind is None:
+            device_kind = (measurements.replay_kind() if mode == "replay"
+                           else "interpret" if interpret
+                           else live_device_kind())
+        self.device_kind = device_kind
         self.components = dict(components)
         self.mode = mode
         self.measurements = measurements or MeasurementSet()
         self.fallback = fallback
-        self.vmem_budget = int(vmem_budget)
+        self.vmem_budget = int(vmem_budget if vmem_budget is not None
+                               else device_vmem_budget(device_kind))
         self.bank_overhead_bytes = int(bank_overhead_bytes)
         self.reps = max(1, int(reps))
         self.native_tile = int(native_tile)
@@ -414,7 +537,15 @@ class PallasOracle(OracleBatchMixin):
         if native_store is not None and native_store.tile:
             self._native_tiles.add(native_store.tile)
         self._specs_cache: Dict[int, Dict[str, PallasKernelSpec]] = {}
-        self._measured: Dict[Tuple[str, int, int, int], float] = {}
+        # memo per (component, ports, unrolls, tile): the measured wall,
+        # or the compiler's refusal reason (a str)
+        self._measured: Dict[Tuple[str, int, int, int], Any] = {}
+        # what the live measurements cost: points timed / refused,
+        # seconds spent compiling vs. in the timed reps, and kernel
+        # components the fallback tool priced instead
+        self.stats: Dict[str, float] = {"timed": 0, "refused": 0,
+                                        "fallback": 0, "compile_s": 0.0,
+                                        "timed_s": 0.0}
         self._lock = threading.Lock()
         # timing under a thread-pool fan-out measures contention, not the
         # kernel: _measure_lock serializes every real measurement even
@@ -451,26 +582,55 @@ class PallasOracle(OracleBatchMixin):
 
     def _measured_here(self, component: str, resolved: int) -> bool:
         """True when (component, resolved tile) is priced by running /
-        replaying a kernel rather than by the fallback tool."""
+        replaying a kernel rather than by the fallback tool.  A record
+        drive has no fallback for a kernel component: a missing store
+        for its key raises."""
         if component not in self.components:
             return False        # kernel coverage is per component name
-        if self._specs_for(resolved) is None:
-            return False
-        if self.mode in ("record", "replay"):
-            return self._store_for(resolved) is not None
-        return True             # measure mode: time it live
+        if self.mode == "replay":
+            return (self._specs_for(resolved) is not None
+                    and self._store_for(resolved) is not None)
+        if self.mode == "record" and self._store_for(resolved) is None:
+            raise MissingMeasurementError(
+                f"record mode has no store for {component!r} under key "
+                f"(tile={resolved}, device={self.device_kind!r}); stores "
+                f"held: {self.measurements.describe()} — open a recording "
+                f"for that key (open_recording) instead of pricing the "
+                f"kernel analytically")
+        return self._specs_for(resolved) is not None
 
     # ------------------------------------------------------------------
     # measurement
     # ------------------------------------------------------------------
-    def _time_runner(self, runner: Callable[[], Any]) -> float:
+    def _time_program(self, program: Any, args: Tuple[Any, ...]) -> Any:
+        """Compile ``program`` for ``args``, warm it up, and return the
+        best of ``reps`` timed launches (seconds), each ending in
+        ``block_until_ready``.  A kernel the TPU compiler refuses — at
+        lowering or at compile time — returns the refusal reason (a
+        str) instead; any other exception propagates."""
         import jax
-        jax.block_until_ready(runner())            # compile + warm up
+        from jax.experimental.pallas import tpu as pltpu
+        t0 = time.perf_counter()
+        try:
+            lowered = program.lower(*args)
+        except (ValueError, NotImplementedError,
+                pltpu.LoweringException) as e:
+            return _refusal("lowering", e)
+        try:
+            compiled = lowered.compile()
+        except jax.errors.JaxRuntimeError as e:
+            return _refusal("compile", e)
+        compile_s = time.perf_counter() - t0
+        jax.block_until_ready(compiled(*args))           # warm-up
         best = float("inf")
+        t_reps = time.perf_counter()
         for _ in range(self.reps):
             t0 = time.perf_counter()
-            jax.block_until_ready(runner())
+            jax.block_until_ready(compiled(*args))
             best = min(best, time.perf_counter() - t0)
+        with self._lock:
+            self.stats["compile_s"] += compile_s
+            self.stats["timed_s"] += time.perf_counter() - t_reps
         return best
 
     def _missing_error(self, key: MeasureKey, resolved: int
@@ -485,7 +645,9 @@ class PallasOracle(OracleBatchMixin):
             f"{self.measurements.describe()}; {hint}")
 
     def _wall_s(self, spec: PallasKernelSpec, ports: int, unrolls: int,
-                resolved: int) -> float:
+                resolved: int) -> Any:
+        """The point's wall seconds (float), or the compiler's refusal
+        reason (str) — measured once, recorded, or replayed."""
         memo_key = (spec.name, ports, unrolls, resolved)
         key: MeasureKey = (spec.name, ports, unrolls)
         store = self._store_for(resolved)
@@ -493,35 +655,44 @@ class PallasOracle(OracleBatchMixin):
             hit = self._measured.get(memo_key)
         if hit is not None:
             return hit
+        recorded = None
+        if store is not None:
+            recorded = store.get(key)
+            if recorded is None:
+                recorded = store.refused.get(key)
         if self.mode == "replay":
-            wall = store.get(key)
-            if wall is None:
+            if recorded is None:
                 raise self._missing_error(key, resolved)
-        elif self.mode == "record" and store.get(key) is not None:
+            wall = recorded
+        elif self.mode == "record" and recorded is not None:
             # resumed campaign: the point was already paid for (and
             # flushed) by the killed run — never re-time it
-            wall = store.get(key)
+            wall = recorded
         else:
             with self._measure_lock:
                 with self._lock:              # raced while waiting?
                     hit = self._measured.get(memo_key)
                 if hit is not None:
                     return hit
+                built = spec.build(ports, unrolls, self.interpret)
                 if self.timer is not None:
                     wall = float(self.timer(spec.name, ports, unrolls,
-                                            spec.build(ports, unrolls,
-                                                       self.interpret)))
+                                            built))
                 else:
-                    wall = self._time_runner(spec.build(ports, unrolls,
-                                                        self.interpret))
+                    wall = self._time_program(*built)
+                with self._lock:
+                    self.stats["refused" if isinstance(wall, str)
+                               else "timed"] += 1
         with self._lock:
             # a racing measurement of the same key keeps the first value,
             # so every consumer sees one number per physical point
             wall = self._measured.setdefault(memo_key, wall)
-            if self.mode == "record" and store.get(key) != wall:
-                store.put(key, wall)         # may autoflush (flush_every)
+            if self.mode == "record" and recorded is None:
+                if isinstance(wall, str):
+                    store.refuse(key, wall)
+                else:
+                    store.put(key, wall)     # may autoflush (flush_every)
         return wall
-
     # ------------------------------------------------------------------
     # cost composition
     # ------------------------------------------------------------------
@@ -571,9 +742,8 @@ class PallasOracle(OracleBatchMixin):
             if self.fallback is None:
                 raise KeyError(f"no Pallas kernel or fallback tool for "
                                f"component {component!r} (tile={tile})")
-            return call_synthesize(self.fallback, component,
-                                   unrolls=unrolls, ports=ports,
-                                   max_states=max_states, tile=tile)
+            return self._fallback_synthesize(component, unrolls, ports,
+                                             max_states, tile)
         spec = self._specs_for(resolved)[component]
         if not spec.divisible(ports, unrolls):
             return self._infeasible(ports, unrolls, 0, tile)
@@ -592,9 +762,15 @@ class PallasOracle(OracleBatchMixin):
         except MissingMeasurementError:
             if self.missing != "fallback":
                 raise
-            return call_synthesize(self.fallback, component,
-                                   unrolls=unrolls, ports=ports,
-                                   max_states=max_states, tile=tile)
+            return self._fallback_synthesize(component, unrolls, ports,
+                                             max_states, tile)
+        if isinstance(wall, str):
+            # the TPU compiler refused this kernel: a failed synthesis,
+            # counted by the ledger like any other
+            return Synthesis(lam=float("inf"), area=float("inf"),
+                             ports=ports, unrolls=unrolls,
+                             states_per_iter=states, feasible=False,
+                             detail={"refused": wall}, tile=tile)
         lam = wall / ports                       # parallel lane-banks
         area = self._area_bytes(spec, ports, unrolls)
         return Synthesis(
@@ -604,6 +780,15 @@ class PallasOracle(OracleBatchMixin):
                     "grid_steps": float(spec.grid_steps(
                         H, W, ports=ports, unrolls=unrolls))},
             tile=tile)
+
+    def _fallback_synthesize(self, component: str, unrolls: int, ports: int,
+                             max_states: Optional[int],
+                             tile: int) -> Synthesis:
+        if component in self.components:
+            with self._lock:
+                self.stats["fallback"] += 1
+        return call_synthesize(self.fallback, component, unrolls=unrolls,
+                               ports=ports, max_states=max_states, tile=tile)
 
     def cdfg_facts(self, component: str, synth: Synthesis) -> CDFGFacts:
         # a feasible measured-tile synthesis without a measured wall came
@@ -650,31 +835,34 @@ class PallasOracle(OracleBatchMixin):
         return saved[0] if saved else None
 
 
-def _default_device_kind() -> str:
-    """The real-device tag for non-interpret measurements."""
-    try:
-        import jax
-        return str(jax.default_backend())
-    except Exception:           # pragma: no cover - jax always importable
-        return "device"
+def _refusal(phase: str, exc: Exception) -> str:
+    """The one-line reason a refused kernel is tagged with."""
+    first = (str(exc).strip().splitlines() or [""])[0]
+    return f"{phase}: {type(exc).__name__}: {first}"
 
 
-def open_recording(path: str, *, mode: str, tile: int = 0,
-                   interpret: bool = True,
-                   flush_every: int = 16) -> MeasurementSet:
+def open_recording(path: str, *, mode: str, device_kind: str,
+                   tile: int = 0, flush_every: int = 16) -> MeasurementSet:
     """The record/replay bootstrap every app shares: load ``path`` when
     it exists (replay always loads — a missing file should fail loudly),
-    otherwise start a fresh tagged store for a record campaign, and wrap
-    the result as a single-recording :class:`MeasurementSet`.  Record
-    mode autoflushes every ``flush_every`` timings; replay never writes.
+    otherwise start a fresh store tagged ``device_kind`` for a record
+    campaign, and wrap the result as a single-recording
+    :class:`MeasurementSet`.  Record mode autoflushes every
+    ``flush_every`` timings; replay never writes.  A record campaign
+    never resumes a file made on another device kind: each kind keeps
+    its own file (see ``default_measurement_path`` of each app).
     """
     autoflush = flush_every if mode == "record" else 0
     if mode == "replay" or os.path.exists(path):
         store = MeasurementStore.load(path, flush_every=autoflush)
+        if mode == "record" and store.device_kind != device_kind:
+            raise ValueError(
+                f"{path} holds {store.device_kind!r} walls; a "
+                f"{device_kind!r} recording needs a file of its own")
     else:
-        kind = "interpret" if interpret else _default_device_kind()
         store = MeasurementStore(path,
-                                 meta={"tile": tile, "interpret": interpret,
-                                       "device_kind": kind},
+                                 meta={"tile": tile,
+                                       "interpret": device_kind == "interpret",
+                                       "device_kind": device_kind},
                                  flush_every=autoflush)
     return MeasurementSet.from_store(store, tile=tile)

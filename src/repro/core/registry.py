@@ -326,8 +326,7 @@ def _pallas_tool(app: App, *, share_plm: bool = False,
         if app.calibrated_fallback is not None:
             # hand the hook the already-loaded native recording so the
             # unit fit does not re-read the JSON from disk
-            kind = ("interpret" if opts.get("interpret", True)
-                    else "device")
+            kind = opts.get("device_kind") or measurements.replay_kind()
             fallback = app.calibrated_fallback(
                 store=measurements.get(app.native_tile, kind))
         else:

@@ -25,8 +25,6 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..utils import keystr_path
-
 __all__ = [
     "tree_paths", "ShardingRules", "lm_rules", "mesh_context",
     "residual_sharding", "constrain", "constrain_residual",
@@ -43,7 +41,8 @@ _RESIDUAL_STACK: List[Tuple[Axis, ...]] = [("data", None, None)]
 def tree_paths(tree: Any) -> Any:
     """Same-structure tree whose leaves are 'a/b/0'-style path strings."""
     flat, treedef = jax.tree_util.tree_flatten_with_path(tree)
-    paths = [keystr_path(kp) for kp, _ in flat]
+    paths = [jax.tree_util.keystr(kp, simple=True, separator="/")
+             for kp, _ in flat]
     return jax.tree_util.tree_unflatten(treedef, paths)
 
 

@@ -27,12 +27,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-if not hasattr(pltpu, "CompilerParams"):   # jax < 0.5: old class name
-    pltpu.CompilerParams = pltpu.TPUCompilerParams
-
 __all__ = ["flash_attention"]
 
 NEG_INF = -1e30
+# f32 matmuls at full precision: the MXU's default would round the
+# operands to bf16 and drift from the f32 reference
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
@@ -51,7 +51,8 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     k = k_ref[0, 0].astype(jnp.float32)                  # (bkv, d)
     v = v_ref[0, 0].astype(jnp.float32)
 
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))   # (bq, bkv)
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            precision=_HIGHEST)                 # (bq, bkv)
     if softcap and softcap > 0:
         s = jnp.tanh(s / softcap) * softcap
 
@@ -74,7 +75,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     p = jnp.where(ok, p, 0.0)
     l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=1)
     acc_scr[...] = acc_scr[...] * corr[:, None] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())))
+        p, v, (((1,), (0,)), ((), ())), precision=_HIGHEST)
     m_scr[...] = m_new
 
     @pl.when(ikv == n_kv - 1)

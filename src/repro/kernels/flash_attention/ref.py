@@ -28,7 +28,9 @@ def attention_ref(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     kf = k.astype(jnp.float32)
     vf = v.astype(jnp.float32)
     qf = qf.reshape(B, K, G, Sq, d)
-    s = jnp.einsum("bkgqd,bksd->bkgqs", qf, kf)
+    # full f32 precision: a TPU's default matmul would round to bf16
+    hi = jax.lax.Precision.HIGHEST
+    s = jnp.einsum("bkgqd,bksd->bkgqs", qf, kf, precision=hi)
     if softcap and softcap > 0:
         s = jnp.tanh(s / softcap) * softcap
     q_pos = jnp.arange(Sq) + q_offset
@@ -41,5 +43,5 @@ def attention_ref(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         ok &= dist < window
     s = jnp.where(ok[None, None, None], s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bkgqs,bksd->bkgqd", p, vf)
+    o = jnp.einsum("bkgqs,bksd->bkgqd", p, vf, precision=hi)
     return o.reshape(B, H, Sq, d).astype(q.dtype)

@@ -8,9 +8,15 @@ kernel streams x/dt/B/C chunk tiles HBM->VMEM exactly once and never
 materializes the (S x S) dual form.
 
 Grid: (Bz, H, n_chunks), last dimension "arbitrary" (sequential).
-Block shapes: x (1,1,Q,P), dt (1,1,Q), B/C (1,Q,N) shared across heads,
+Block shapes: x (1,1,Q,P), dt as a (Q,1) column and a (1,Q) row (the
+kernel needs both orientations and the TPU cannot cheaply transpose a
+vector), A as a (1,1) tile per head, B/C (1,Q,N) shared across heads,
 outputs y (1,1,Q,P) and the final state (1,1,P,N) written on the last
-chunk.  Q and N default to 128 (lane-width aligned); P is the head dim.
+chunk.  Every block spans its array's two trailing dimensions or is
+(8, 128)-aligned, so any chunk that is a multiple of 8 lowers on the
+chip.  The within-chunk cumulative sums are masked reductions over the
+(Q, Q) causal triangle, and the matmuls run at full f32 precision.
+Q and N default to 128 (lane-width aligned); P is the head dim.
 """
 
 from __future__ import annotations
@@ -22,55 +28,60 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-if not hasattr(pltpu, "CompilerParams"):   # jax < 0.5: old class name
-    pltpu.CompilerParams = pltpu.TPUCompilerParams
-
 __all__ = ["ssd_scan"]
 
+_HIGHEST = jax.lax.Precision.HIGHEST
 
-def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, hout_ref, h_scr, *,
-            chunk: int, n_chunks: int):
+
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, (dims, ((), ())), precision=_HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
+def _kernel(x_ref, dtc_ref, dtr_ref, a_ref, b_ref, c_ref, y_ref, hout_ref,
+            h_scr, *, n_chunks: int):
     c_idx = pl.program_id(2)
 
     @pl.when(c_idx == 0)
     def _init():
         h_scr[...] = jnp.zeros_like(h_scr)
 
-    x = x_ref[0, 0].astype(jnp.float32)        # (Q, P)
-    dt = dt_ref[0, 0].astype(jnp.float32)      # (Q,)
-    A = a_ref[0]                               # ()
-    Bm = b_ref[0].astype(jnp.float32)          # (Q, N)
-    Cm = c_ref[0].astype(jnp.float32)          # (Q, N)
+    x = x_ref[...].astype(jnp.float32)         # (Q, P)
+    dt_col = dtc_ref[...].astype(jnp.float32)  # (Q, 1)
+    dt_row = dtr_ref[...].astype(jnp.float32)  # (1, Q)
+    A = a_ref[...]                             # (1, 1)
+    Bm = b_ref[...].astype(jnp.float32)        # (Q, N)
+    Cm = c_ref[...].astype(jnp.float32)        # (Q, N)
 
-    a = dt * A                                 # (Q,) log-decay steps
-    cum = jnp.cumsum(a)                        # within-chunk cumulative
+    a_col, a_row = dt_col * A, dt_row * A      # log-decay steps
+    Q = x.shape[0]
+    row = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
+    causal = row >= col
+    # within-chunk cumulative sums, as a column and as a row
+    cum_col = jnp.sum(jnp.where(causal, a_row, 0.0), axis=1, keepdims=True)
+    cum_row = jnp.sum(jnp.where(row <= col, a_col, 0.0), axis=0,
+                      keepdims=True)
 
     # intra-chunk dual form: scores (Q, Q) = (C_i . B_j) * L_ij * dt_j
-    s = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())))   # (Q, Q)
-    diff = cum[:, None] - cum[None, :]
-    row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    s = _dot(Cm, Bm, ((1,), (1,)))                                  # (Q, Q)
     # mask before exp (masked diffs are positive and would overflow)
-    L = jnp.exp(jnp.where(row >= col, diff, -1e30))
-    w = s * L * dt[None, :]
-    y = jax.lax.dot_general(w, x, (((1,), (0,)), ((), ())))     # (Q, P)
+    L = jnp.exp(jnp.where(causal, cum_col - cum_row, -1e30))
+    y = _dot(s * L * dt_row, x, ((1,), (0,)))                       # (Q, P)
 
     # inter-chunk: y += C_i . (exp(cum_i) * h_in)
-    h = h_scr[...]                                               # (P, N)
-    y_inter = jax.lax.dot_general(Cm, h, (((1,), (1,)), ((), ())))  # (Q, P)
-    y = y + y_inter * jnp.exp(cum)[:, None]
-    y_ref[0, 0] = y.astype(y_ref.dtype)
+    h = h_scr[...]                                                  # (P, N)
+    y = y + _dot(Cm, h, ((1,), (1,))) * jnp.exp(cum_col)
+    y_ref[...] = y.astype(y_ref.dtype)
 
     # state update: h' = exp(sum a) * h + sum_j exp(cum_Q - cum_j) dt_j x_j B_j^T
-    total = cum[-1]
-    rem = jnp.exp(total - cum) * dt                              # (Q,)
-    contrib = jax.lax.dot_general(x * rem[:, None], Bm,
-                                  (((0,), (0,)), ((), ())))      # (P, N)
-    h_scr[...] = jnp.exp(total) * h + contrib
+    total = jnp.sum(a_row, axis=1, keepdims=True)                   # (1, 1)
+    rem = jnp.exp(total - cum_col) * dt_col                         # (Q, 1)
+    h_scr[...] = jnp.exp(total) * h + _dot(x * rem, Bm, ((0,), (0,)))
 
     @pl.when(c_idx == n_chunks - 1)
     def _finish():
-        hout_ref[0, 0] = h_scr[...]
+        hout_ref[...] = h_scr[...]
 
 
 def ssd_scan(x, dt, A, B, C, *, chunk: int = 128, interpret: bool = False):
@@ -86,21 +97,27 @@ def ssd_scan(x, dt, A, B, C, *, chunk: int = 128, interpret: bool = False):
 
     xt = x.transpose(0, 2, 1, 3)               # (Bz,H,S,P)
     dtt = dt.transpose(0, 2, 1)                # (Bz,H,S)
+    dt_col = dtt[..., None]                    # (Bz,H,S,1)
+    dt_row = dtt.reshape(Bz, H, n_chunks, 1, chunk)
+    a3 = A.astype(jnp.float32).reshape(H, 1, 1)
 
-    kern = functools.partial(_kernel, chunk=chunk, n_chunks=n_chunks)
+    sq = pl.squeezed
+    kern = functools.partial(_kernel, n_chunks=n_chunks)
     y, h_fin = pl.pallas_call(
         kern,
         grid=(Bz, H, n_chunks),
         in_specs=[
-            pl.BlockSpec((1, 1, chunk, P), lambda b, h, c: (b, h, c, 0)),
-            pl.BlockSpec((1, 1, chunk), lambda b, h, c: (b, h, c)),
-            pl.BlockSpec((1,), lambda b, h, c: (h,)),
-            pl.BlockSpec((1, chunk, N), lambda b, h, c: (b, c, 0)),
-            pl.BlockSpec((1, chunk, N), lambda b, h, c: (b, c, 0)),
+            pl.BlockSpec((sq, sq, chunk, P), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((sq, sq, chunk, 1), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((sq, sq, sq, 1, chunk),
+                         lambda b, h, c: (b, h, c, 0, 0)),
+            pl.BlockSpec((sq, 1, 1), lambda b, h, c: (h, 0, 0)),
+            pl.BlockSpec((sq, chunk, N), lambda b, h, c: (b, c, 0)),
+            pl.BlockSpec((sq, chunk, N), lambda b, h, c: (b, c, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, chunk, P), lambda b, h, c: (b, h, c, 0)),
-            pl.BlockSpec((1, 1, P, N), lambda b, h, c: (b, h, 0, 0)),
+            pl.BlockSpec((sq, sq, chunk, P), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((sq, sq, P, N), lambda b, h, c: (b, h, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((Bz, H, S, P), x.dtype),
@@ -110,5 +127,5 @@ def ssd_scan(x, dt, A, B, C, *, chunk: int = 128, interpret: bool = False):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(xt, dtt, A.astype(jnp.float32), B, C)
+    )(xt, dt_col, dt_row, a3, B, C)
     return y.transpose(0, 2, 1, 3), h_fin

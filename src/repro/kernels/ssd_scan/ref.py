@@ -31,7 +31,8 @@ def ssd_ref(x, dt, A, B, C, h0=None):
         contrib = (dtt[..., None, None]
                    * xt[..., :, None] * bt[:, None, None, :])  # (Bz,H,P,N)
         h = h * decay + contrib
-        y = jnp.einsum("bn,bhpn->bhp", ct, h)
+        y = jnp.einsum("bn,bhpn->bhp", ct, h,
+                       precision=jax.lax.Precision.HIGHEST)
         return h, y
 
     xs = (x.astype(jnp.float32).transpose(1, 0, 2, 3),

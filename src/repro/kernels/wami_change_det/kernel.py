@@ -3,9 +3,9 @@
 The heaviest WAMI stage: every pixel carries a K=3 Gaussian-mixture
 background state (mu, var, w) that is matched, updated, and renormalized
 each frame.  Knob geometry per DESIGN.md §2 (``ports`` lane-banks x
-``unrolls`` rows per grid step); the mixture state rides along as
-(K, H, W) planes whose BlockSpec blocks the pixel axes and keeps the
-K axis whole, so each grid step owns the full mixture for its tile.
+``unrolls`` rows per grid step); the gray plane and the 3K mixture
+planes ride in one banked stack (``wami_common``), so each grid step
+owns the full mixture for its tile.
 
 The argmin/one-hot over K is unrolled by hand (K=3): first-index
 tie-breaking matches ``jnp.argmin`` exactly, and the unrolled compares
@@ -16,12 +16,9 @@ from __future__ import annotations
 
 import functools
 
-import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-from ..wami_common import (grid_steps_model, knob_blocks, parallel_params,
-                           tile_spec, vmem_bytes_model)
+from ..wami_common import banked_call, grid_steps_model, vmem_bytes_model
 
 __all__ = ["change_detection_kernel", "vmem_bytes", "grid_steps"]
 
@@ -38,10 +35,11 @@ def _first_min_onehot(v0, v1, v2):
     return b0, b1, b2
 
 
-def _kernel(g_ref, mu_ref, var_ref, w_ref,
-            mask_ref, mu_o, var_o, w_o, *, lr, mahal, fg):
-    x = g_ref[...][None]                               # (1, bh, bw)
-    mu, var, w = mu_ref[...], var_ref[...], w_ref[...]  # (K, bh, bw)
+def _kernel(in_ref, out_ref, *, lr, mahal, fg):
+    x = in_ref[0:1]                                    # (1, bh, bw)
+    mu = in_ref[1:1 + _K]                              # (K, bh, bw)
+    var = in_ref[1 + _K:1 + 2 * _K]
+    w = in_ref[1 + 2 * _K:]
     d2 = (x - mu) ** 2 / jnp.maximum(var, 1e-4)
     match = d2 < mahal
     any_match = match[0] | match[1] | match[2]
@@ -63,10 +61,10 @@ def _kernel(g_ref, mu_ref, var_ref, w_ref,
     # foreground: matched component is low-weight, or no match at all
     matched_w = (onehot * w).sum(axis=0)
     mask = (~any_match) | (matched_w < (1.0 - fg))
-    mask_ref[...] = mask.astype(mu.dtype)
-    mu_o[...] = mu_n
-    var_o[...] = var_n
-    w_o[...] = w_n
+    out_ref[0] = mask.astype(mu.dtype)
+    out_ref[1:1 + _K] = mu_n
+    out_ref[1 + _K:1 + 2 * _K] = var_n
+    out_ref[1 + 2 * _K:] = w_n
 
 
 def change_detection_kernel(gray: jnp.ndarray, mu: jnp.ndarray,
@@ -80,23 +78,12 @@ def change_detection_kernel(gray: jnp.ndarray, mu: jnp.ndarray,
     Returns (mask (H, W) in {0.0, 1.0}, mu', var', w') with state in the
     (H, W, K) layout of the reference.
     """
-    H, W = gray.shape
-    bh, bw = knob_blocks(H, W, ports=ports, unrolls=unrolls)
-    spec = tile_spec(bh, bw)
-    spec_k = pl.BlockSpec((_K, bh, bw), lambda i, j: (0, i, j))
-    planes = lambda a: jnp.moveaxis(a, -1, 0)          # (H,W,K) -> (K,H,W)
-    mask, mu_n, var_n, w_n = pl.pallas_call(
+    planes = jnp.concatenate([gray[..., None], mu, var, w], axis=-1)
+    out = banked_call(
         functools.partial(_kernel, lr=lr, mahal=mahal_thresh, fg=fg_thresh),
-        grid=(H // bh, ports),
-        in_specs=[spec, spec_k, spec_k, spec_k],
-        out_specs=[spec, spec_k, spec_k, spec_k],
-        out_shape=[jax.ShapeDtypeStruct((H, W), gray.dtype)]
-        + [jax.ShapeDtypeStruct((_K, H, W), gray.dtype)] * 3,
-        compiler_params=parallel_params(),
-        interpret=interpret,
-    )(gray, planes(mu), planes(var), planes(w))
-    back = lambda a: jnp.moveaxis(a, 0, -1)
-    return mask, back(mu_n), back(var_n), back(w_n)
+        planes, _N_OUT, ports=ports, unrolls=unrolls, interpret=interpret)
+    return (out[..., 0], out[..., 1:1 + _K], out[..., 1 + _K:1 + 2 * _K],
+            out[..., 1 + 2 * _K:])
 
 
 vmem_bytes = functools.partial(vmem_bytes_model, n_in=_N_IN, n_out=_N_OUT)

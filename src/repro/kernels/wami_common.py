@@ -1,7 +1,7 @@
 """Shared plumbing for the COSMOS-knob WAMI kernels (DESIGN.md §2).
 
 Every WAMI stage kernel maps the paper's two knobs onto the same
-BlockSpec/grid geometry, established by ``wami_gradient``:
+BlockSpec/grid geometry:
 
   * ``ports``   -> number of column banks: the W axis splits into
     ``ports`` lane-blocks processed by parallel grid columns (the
@@ -9,25 +9,30 @@ BlockSpec/grid geometry, established by ``wami_gradient``:
   * ``unrolls`` -> rows computed per grid step (``block_h``): loop-body
     replication, trading VMEM footprint for fewer grid iterations.
 
-This module holds the helpers those kernels share: the jax<0.5 compat
-shim for ``pltpu.CompilerParams``, the knob -> (grid, BlockSpec)
-translation, and the VMEM/grid cost models parameterized by the number
-of input/output blocks a kernel touches per grid step.
+The ops wrappers lay each stage's planes out *banked*: an (H, W, C)
+plane stack becomes (H/unrolls, ports, C, unrolls, W/ports), row groups
+and lane-banks as leading axes.  A grid cell's block (C, unrolls,
+W/ports) then spans the array's trailing dimensions at every knob
+point, which is what the TPU's (8, 128) tiling rule asks of a block
+that is not itself tile-aligned — so ports > 1 and unrolls that are no
+multiple of 8 lower on the chip, not only in interpret mode.
+
+This module holds the knob -> (grid, BlockSpec) translation, the banked
+layout, and the VMEM/grid cost models parameterized by the number of
+input/output blocks a kernel touches per grid step.
 """
 
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Callable, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-if not hasattr(pltpu, "CompilerParams"):   # jax < 0.5: old class name
-    pltpu.CompilerParams = pltpu.TPUCompilerParams
-
-__all__ = ["pltpu", "knob_blocks", "tile_spec", "parallel_params",
-           "arbitrary_params", "vmem_bytes_model", "grid_steps_model"]
+__all__ = ["knob_blocks", "to_banks", "from_banks", "bank_spec",
+           "banked_call", "vmem_bytes_model", "grid_steps_model"]
 
 
 def knob_blocks(H: int, W: int, *, ports: int, unrolls: int
@@ -40,23 +45,51 @@ def knob_blocks(H: int, W: int, *, ports: int, unrolls: int
     return unrolls, W // ports
 
 
-def tile_spec(bh: int, bw: int) -> pl.BlockSpec:
-    """The canonical (rows, lane-bank) block: grid cell (i, j) covers
-    rows [i*bh, (i+1)*bh) of bank j."""
-    return pl.BlockSpec((bh, bw), lambda i, j: (i, j))
+def to_banks(planes: jnp.ndarray, *, ports: int, unrolls: int
+             ) -> jnp.ndarray:
+    """(H, W, C) plane stack -> banked (H/unrolls, ports, C, unrolls,
+    W/ports): grid cell (i, j) owns rows [i*unrolls, (i+1)*unrolls) of
+    lane-bank j, for all C planes."""
+    H, W, C = planes.shape
+    bh, bw = knob_blocks(H, W, ports=ports, unrolls=unrolls)
+    return planes.reshape(H // bh, bh, ports, bw, C).transpose(0, 2, 4, 1, 3)
 
 
-def parallel_params() -> "pltpu.CompilerParams":
-    """Both grid axes independent (elementwise/stencil stages)."""
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel"))
+def from_banks(banked: jnp.ndarray) -> jnp.ndarray:
+    """Inverse of :func:`to_banks`: banked -> (H, W, C)."""
+    G, P, C, bh, bw = banked.shape
+    return banked.transpose(0, 3, 1, 4, 2).reshape(G * bh, P * bw, C)
 
 
-def arbitrary_params() -> "pltpu.CompilerParams":
-    """Sequential grid walk — required when the kernel accumulates into
-    an output block shared across grid steps (reductions)."""
-    return pltpu.CompilerParams(
-        dimension_semantics=("arbitrary", "arbitrary"))
+def bank_spec(channels: int, bh: int, bw: int) -> pl.BlockSpec:
+    """Grid cell (i, j) -> the (channels, bh, bw) block of row group i,
+    lane-bank j."""
+    return pl.BlockSpec((pl.squeezed, pl.squeezed, channels, bh, bw),
+                        lambda i, j: (i, j, 0, 0, 0))
+
+
+def banked_call(body: Callable, planes: jnp.ndarray, n_out: int, *,
+                ports: int, unrolls: int, interpret: bool,
+                out_dtype: Optional[jnp.dtype] = None) -> jnp.ndarray:
+    """Run ``body(in_ref, out_ref)`` over the (H/unrolls, ports) knob
+    grid.  ``planes`` is the stage's (H, W, C) input stack; the kernel
+    sees a (C, unrolls, W/ports) input block and writes an (n_out,
+    unrolls, W/ports) output block.  Returns the (H, W, n_out) stack.
+    Both grid axes are independent (elementwise/stencil stages)."""
+    H, W, C = planes.shape
+    bh, bw = knob_blocks(H, W, ports=ports, unrolls=unrolls)
+    out = pl.pallas_call(
+        body,
+        grid=(H // bh, ports),
+        in_specs=[bank_spec(C, bh, bw)],
+        out_specs=bank_spec(n_out, bh, bw),
+        out_shape=jax.ShapeDtypeStruct((H // bh, ports, n_out, bh, bw),
+                                       out_dtype or planes.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=interpret,
+    )(to_banks(planes, ports=ports, unrolls=unrolls))
+    return from_banks(out)
 
 
 def vmem_bytes_model(H: int, W: int, *, ports: int, unrolls: int,

@@ -1,7 +1,8 @@
 """WAMI debayer (bilinear RGGB demosaic) as a Pallas kernel.
 
-COSMOS knobs follow the wami_gradient geometry (DESIGN.md §2): ``ports``
-column lane-banks x ``unrolls`` rows per grid step.  Like the gradient,
+COSMOS knobs follow the shared banked geometry of ``wami_common``
+(DESIGN.md §2): ``ports`` column lane-banks x ``unrolls`` rows per grid
+step.  Like the gradient,
 the halo problem is solved the TPU way: the ops wrapper materializes the
 nine shifted views (center + 8-neighbourhood) with XLA slices, and the
 kernel consumes aligned blocks.  The RGGB parity pattern is recovered
@@ -18,22 +19,20 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ..wami_common import (grid_steps_model, knob_blocks, parallel_params,
-                           tile_spec, vmem_bytes_model)
+from ..wami_common import banked_call, grid_steps_model, vmem_bytes_model
 
 __all__ = ["debayer_kernel", "vmem_bytes", "grid_steps"]
 
 _N_IN, _N_OUT = 9, 3
 
 
-def _kernel(c_ref, n_ref, s_ref, w_ref, e_ref, nw_ref, ne_ref, sw_ref,
-            se_ref, r_ref, g_ref, b_ref):
-    bh, bw = c_ref.shape
-    c = c_ref[...]
-    cross = (n_ref[...] + s_ref[...] + w_ref[...] + e_ref[...]) * 0.25
-    diag = (nw_ref[...] + ne_ref[...] + sw_ref[...] + se_ref[...]) * 0.25
-    horiz = (w_ref[...] + e_ref[...]) * 0.5
-    vert = (n_ref[...] + s_ref[...]) * 0.5
+def _kernel(v_ref, rgb_ref):
+    _, bh, bw = v_ref.shape
+    c, n, s, w, e, nw, ne, sw, se = (v_ref[k] for k in range(_N_IN))
+    cross = (n + s + w + e) * 0.25
+    diag = (nw + ne + sw + se) * 0.25
+    horiz = (w + e) * 0.5
+    vert = (n + s) * 0.5
 
     # global pixel parity: the block at grid cell (i, j) starts at row
     # i*bh, column j*bw of the full frame
@@ -47,10 +46,10 @@ def _kernel(c_ref, n_ref, s_ref, w_ref, e_ref, nw_ref, ne_ref, sw_ref,
     g2_loc = (~even_y) & even_x              # (1,0)=G
     b_loc = (~even_y) & (~even_x)            # (1,1)=B
 
-    r_ref[...] = jnp.where(r_loc, c, jnp.where(g1_loc, horiz,
+    rgb_ref[0] = jnp.where(r_loc, c, jnp.where(g1_loc, horiz,
                            jnp.where(g2_loc, vert, diag)))
-    g_ref[...] = jnp.where(r_loc | b_loc, cross, c)
-    b_ref[...] = jnp.where(b_loc, c, jnp.where(g2_loc, horiz,
+    rgb_ref[1] = jnp.where(r_loc | b_loc, cross, c)
+    rgb_ref[2] = jnp.where(b_loc, c, jnp.where(g2_loc, horiz,
                            jnp.where(g1_loc, vert, diag)))
 
 
@@ -58,25 +57,14 @@ def debayer_kernel(bayer: jnp.ndarray, *, ports: int = 1, unrolls: int = 8,
                    interpret: bool = False) -> jnp.ndarray:
     """bayer: (H, W) RGGB mosaic -> (H, W, 3) float32 RGB."""
     img = bayer.astype(jnp.float32)
-    H, W = img.shape
-    bh, bw = knob_blocks(H, W, ports=ports, unrolls=unrolls)
     p = jnp.pad(img, 1, mode="reflect")
     views = (p[1:-1, 1:-1],                              # c
              p[:-2, 1:-1], p[2:, 1:-1],                  # n, s
              p[1:-1, :-2], p[1:-1, 2:],                  # w, e
              p[:-2, :-2], p[:-2, 2:],                    # nw, ne
              p[2:, :-2], p[2:, 2:])                      # sw, se
-    spec = tile_spec(bh, bw)
-    r, g, b = pl.pallas_call(
-        _kernel,
-        grid=(H // bh, ports),
-        in_specs=[spec] * 9,
-        out_specs=[spec] * 3,
-        out_shape=[jax.ShapeDtypeStruct((H, W), jnp.float32)] * 3,
-        compiler_params=parallel_params(),
-        interpret=interpret,
-    )(*views)
-    return jnp.stack([r, g, b], axis=-1)
+    return banked_call(_kernel, jnp.stack(views, axis=-1), _N_OUT,
+                       ports=ports, unrolls=unrolls, interpret=interpret)
 
 
 vmem_bytes = functools.partial(vmem_bytes_model, n_in=_N_IN, n_out=_N_OUT)

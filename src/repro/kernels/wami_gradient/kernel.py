@@ -9,6 +9,10 @@ This is the paper's port/unroll knob pair made physical on TPU
   * ``unrolls`` -> rows computed per grid step (``block_h``): the loop
     body replication, trading VMEM footprint for fewer grid iterations.
 
+Both knobs index the banked layout of ``wami_common``, so every knob
+point's block spans its array's trailing dimensions and lowers on the
+chip.
+
 The halo problem (vertical neighbours across block boundaries) is solved
 the TPU way: the ops wrapper materializes the four shifted views with
 XLA slices and the kernel consumes aligned blocks — no shared-memory
@@ -20,50 +24,28 @@ VMEM bytes x grid steps) is exercised in benchmarks/fig4_motivational.py.
 
 from __future__ import annotations
 
-import functools
-
-import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-if not hasattr(pltpu, "CompilerParams"):   # jax < 0.5: old class name
-    pltpu.CompilerParams = pltpu.TPUCompilerParams
+from ..wami_common import banked_call
 
 __all__ = ["gradient_kernel", "vmem_bytes", "grid_steps"]
 
 
-def _kernel(left_ref, right_ref, up_ref, down_ref, gx_ref, gy_ref):
-    gx_ref[...] = (right_ref[...] - left_ref[...]) * 0.5
-    gy_ref[...] = (down_ref[...] - up_ref[...]) * 0.5
+def _kernel(v_ref, g_ref):
+    left, right, up, down = (v_ref[k] for k in range(4))
+    g_ref[0] = (right - left) * 0.5
+    g_ref[1] = (down - up) * 0.5
 
 
 def gradient_kernel(gray: jnp.ndarray, *, ports: int = 1, unrolls: int = 8,
                     interpret: bool = False):
     """Central-difference gradient.  gray: (H, W) with W % ports == 0 and
     H % unrolls == 0.  Returns (gx, gy)."""
-    H, W = gray.shape
-    assert W % ports == 0 and H % unrolls == 0
-    bw = W // ports
-    bh = unrolls
     p = jnp.pad(gray, 1, mode="edge")
-    left = p[1:-1, :-2]
-    right = p[1:-1, 2:]
-    up = p[:-2, 1:-1]
-    down = p[2:, 1:-1]
-
-    spec = pl.BlockSpec((bh, bw), lambda i, j: (i, j))
-    gx, gy = pl.pallas_call(
-        _kernel,
-        grid=(H // bh, ports),
-        in_specs=[spec] * 4,
-        out_specs=[spec, spec],
-        out_shape=[jax.ShapeDtypeStruct((H, W), gray.dtype)] * 2,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
-        interpret=interpret,
-    )(left, right, up, down)
-    return gx, gy
+    views = (p[1:-1, :-2], p[1:-1, 2:], p[:-2, 1:-1], p[2:, 1:-1])
+    g = banked_call(_kernel, jnp.stack(views, axis=-1), 2, ports=ports,
+                    unrolls=unrolls, interpret=interpret)
+    return g[..., 0], g[..., 1]
 
 
 def vmem_bytes(H: int, W: int, *, ports: int, unrolls: int,

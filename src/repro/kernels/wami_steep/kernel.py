@@ -11,7 +11,11 @@ lane-banks x ``unrolls`` rows per grid step):
   * ``hessian_kernel`` — the reduction H = sum_x sd(x)^T sd(x): each
     grid step contracts its (6, bh*bw) block on the MXU and accumulates
     into a single (6, 6) output block shared by every step, which forces
-    an ``arbitrary`` (sequential) grid walk.
+    an ``arbitrary`` (sequential) grid walk.  The ops wrapper hands each
+    step its block already flattened (the banked layout of
+    ``wami_common`` with the (bh, bw) pixels merged), and the
+    contraction runs at full f32 precision so the result does not depend
+    on the MXU's default bf16 passes.
 """
 
 from __future__ import annotations
@@ -21,9 +25,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from ..wami_common import (arbitrary_params, grid_steps_model, knob_blocks,
-                           parallel_params, tile_spec, vmem_bytes_model)
+from ..wami_common import (banked_call, grid_steps_model, knob_blocks,
+                           to_banks, vmem_bytes_model)
 
 __all__ = ["steepest_descent_kernel", "hessian_kernel",
            "vmem_bytes", "grid_steps", "hessian_vmem_bytes"]
@@ -31,51 +36,41 @@ __all__ = ["steepest_descent_kernel", "hessian_kernel",
 _N_IN, _N_OUT = 2, 6      # steepest descent: gx, gy -> 6 sd planes
 
 
-def _sd_kernel(gx_ref, gy_ref, s0, s1, s2, s3, s4, s5):
-    bh, bw = gx_ref.shape
-    gx, gy = gx_ref[...], gy_ref[...]
+def _sd_kernel(g_ref, sd_ref):
+    _, bh, bw = g_ref.shape
+    gx, gy = g_ref[0], g_ref[1]
     yy = (jax.lax.broadcasted_iota(jnp.int32, (bh, bw), 0)
           + pl.program_id(0) * bh).astype(gx.dtype)
     xx = (jax.lax.broadcasted_iota(jnp.int32, (bh, bw), 1)
           + pl.program_id(1) * bw).astype(gx.dtype)
-    s0[...] = gx * xx
-    s1[...] = gx * yy
-    s2[...] = gx
-    s3[...] = gy * xx
-    s4[...] = gy * yy
-    s5[...] = gy
+    sd_ref[0] = gx * xx
+    sd_ref[1] = gx * yy
+    sd_ref[2] = gx
+    sd_ref[3] = gy * xx
+    sd_ref[4] = gy * yy
+    sd_ref[5] = gy
 
 
 def steepest_descent_kernel(gx: jnp.ndarray, gy: jnp.ndarray, *,
                             ports: int = 1, unrolls: int = 8,
                             interpret: bool = False) -> jnp.ndarray:
     """gx, gy: (H, W) image gradients -> sd images (H, W, 6)."""
-    H, W = gx.shape
-    bh, bw = knob_blocks(H, W, ports=ports, unrolls=unrolls)
-    spec = tile_spec(bh, bw)
-    planes = pl.pallas_call(
-        _sd_kernel,
-        grid=(H // bh, ports),
-        in_specs=[spec] * 2,
-        out_specs=[spec] * 6,
-        out_shape=[jax.ShapeDtypeStruct((H, W), gx.dtype)] * 6,
-        compiler_params=parallel_params(),
-        interpret=interpret,
-    )(gx, gy)
-    return jnp.stack(planes, axis=-1)
+    return banked_call(_sd_kernel, jnp.stack([gx, gy], axis=-1), _N_OUT,
+                       ports=ports, unrolls=unrolls, interpret=interpret)
 
 
-def _hessian_kernel(s0, s1, s2, s3, s4, s5, out_ref):
+def _hessian_kernel(sd_ref, out_ref):
     first = (pl.program_id(0) == 0) & (pl.program_id(1) == 0)
 
     @pl.when(first)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    flat = jnp.stack([s[...].reshape(-1)
-                      for s in (s0, s1, s2, s3, s4, s5)])       # (6, bh*bw)
-    out_ref[...] += jnp.dot(flat, flat.T,
-                            preferred_element_type=out_ref.dtype)
+    flat = sd_ref[...]                                          # (6, bh*bw)
+    out_ref[...] += jax.lax.dot_general(
+        flat, flat, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=out_ref.dtype)
 
 
 def hessian_kernel(sd: jnp.ndarray, *, ports: int = 1, unrolls: int = 8,
@@ -83,16 +78,19 @@ def hessian_kernel(sd: jnp.ndarray, *, ports: int = 1, unrolls: int = 8,
     """sd: (H, W, 6) steepest-descent images -> Hessian (6, 6)."""
     H, W, _ = sd.shape
     bh, bw = knob_blocks(H, W, ports=ports, unrolls=unrolls)
-    spec = tile_spec(bh, bw)
+    flat = to_banks(sd, ports=ports, unrolls=unrolls).reshape(
+        H // bh, ports, 6, bh * bw)
     return pl.pallas_call(
         _hessian_kernel,
         grid=(H // bh, ports),
-        in_specs=[spec] * 6,
+        in_specs=[pl.BlockSpec((pl.squeezed, pl.squeezed, 6, bh * bw),
+                               lambda i, j: (i, j, 0, 0))],
         out_specs=pl.BlockSpec((6, 6), lambda i, j: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((6, 6), sd.dtype),
-        compiler_params=arbitrary_params(),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-    )(*(sd[..., k] for k in range(6)))
+    )(flat)
 
 
 vmem_bytes = functools.partial(vmem_bytes_model, n_in=_N_IN, n_out=_N_OUT)

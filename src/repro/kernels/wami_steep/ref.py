@@ -3,6 +3,7 @@
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 __all__ = ["steepest_descent_ref", "hessian_ref"]
@@ -17,4 +18,5 @@ def steepest_descent_ref(gx: jnp.ndarray, gy: jnp.ndarray) -> jnp.ndarray:
 
 def hessian_ref(sd: jnp.ndarray) -> jnp.ndarray:
     flat = sd.reshape(-1, 6)
-    return flat.T @ flat
+    # full f32 precision: a TPU's default matmul would round to bf16
+    return jnp.matmul(flat.T, flat, precision=jax.lax.Precision.HIGHEST)

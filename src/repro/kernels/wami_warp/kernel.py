@@ -14,23 +14,20 @@ from __future__ import annotations
 
 import functools
 
-import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-from ..wami_common import (grid_steps_model, knob_blocks, parallel_params,
-                           tile_spec, vmem_bytes_model)
+from ..wami_common import banked_call, grid_steps_model, vmem_bytes_model
 
 __all__ = ["warp_blend_kernel", "warp_gather", "vmem_bytes", "grid_steps"]
 
 _N_IN, _N_OUT = 6, 1
 
 
-def _kernel(i00_ref, i01_ref, i10_ref, i11_ref, fx_ref, fy_ref, out_ref):
-    fx, fy = fx_ref[...], fy_ref[...]
-    top = i00_ref[...] * (1 - fx) + i01_ref[...] * fx
-    bot = i10_ref[...] * (1 - fx) + i11_ref[...] * fx
-    out_ref[...] = top * (1 - fy) + bot * fy
+def _kernel(v_ref, out_ref):
+    i00, i01, i10, i11, fx, fy = (v_ref[k] for k in range(_N_IN))
+    top = i00 * (1 - fx) + i01 * fx
+    bot = i10 * (1 - fx) + i11 * fx
+    out_ref[0] = top * (1 - fy) + bot * fy
 
 
 def warp_gather(img: jnp.ndarray, p: jnp.ndarray):
@@ -57,19 +54,9 @@ def warp_blend_kernel(img: jnp.ndarray, p: jnp.ndarray, *, ports: int = 1,
                       unrolls: int = 8, interpret: bool = False
                       ) -> jnp.ndarray:
     """img: (H, W), p: affine params (6,) -> warped (H, W)."""
-    H, W = img.shape
-    bh, bw = knob_blocks(H, W, ports=ports, unrolls=unrolls)
-    planes = warp_gather(img, p)
-    spec = tile_spec(bh, bw)
-    return pl.pallas_call(
-        _kernel,
-        grid=(H // bh, ports),
-        in_specs=[spec] * 6,
-        out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct((H, W), img.dtype),
-        compiler_params=parallel_params(),
-        interpret=interpret,
-    )(*planes)
+    planes = jnp.stack(warp_gather(img, p), axis=-1)
+    return banked_call(_kernel, planes, 1, ports=ports, unrolls=unrolls,
+                       interpret=interpret, out_dtype=img.dtype)[..., 0]
 
 
 vmem_bytes = functools.partial(vmem_bytes_model, n_in=_N_IN, n_out=_N_OUT)

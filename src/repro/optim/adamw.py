@@ -64,9 +64,9 @@ def apply_updates(cfg: AdamWConfig, params: Any, grads: Any, state: OptState,
     import re
     if decay_mask is None:
         pat = re.compile(cfg.no_decay_pattern)
-        from ..utils import keystr_path
         paths = jax.tree_util.tree_map_with_path(
-            lambda kp, _: keystr_path(kp), params)
+            lambda kp, _: jax.tree_util.keystr(kp, simple=True,
+                                               separator="/"), params)
         decay_mask = jax.tree.map(lambda p: 0.0 if pat.search(p) else 1.0, paths)
 
     grads, gnorm = clip_by_global_norm(grads, cfg.clip_norm)

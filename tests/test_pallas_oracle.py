@@ -37,7 +37,7 @@ def _small():
 # ----------------------------------------------------------------------
 def test_non_divisible_knobs_are_infeasible_and_counted():
     sub, _ = _small()
-    ledger = OracleLedger(PallasOracle(sub, timer=_fake_timer))
+    ledger = OracleLedger(PallasOracle(sub, timer=_fake_timer, interpret=True))
     s = ledger.synthesize("gradient", unrolls=5, ports=2)   # 32 % 5 != 0
     assert not s.feasible and math.isinf(s.lam)
     assert ledger.invocations["gradient"] == 1              # Fig. 11 counts it
@@ -48,21 +48,22 @@ def test_non_divisible_knobs_are_infeasible_and_counted():
 
 def test_vmem_budget_is_the_lambda_constraint():
     sub, _ = _small()
-    oracle = PallasOracle(sub, timer=_fake_timer, vmem_budget=1024)
+    oracle = PallasOracle(sub, timer=_fake_timer, interpret=True,
+                          vmem_budget=1024)
     s = oracle.synthesize("gradient", unrolls=8, ports=1)
     assert not s.feasible
 
 
 def test_max_states_cap_discards():
     sub, _ = _small()
-    oracle = PallasOracle(sub, timer=_fake_timer)
+    oracle = PallasOracle(sub, timer=_fake_timer, interpret=True)
     s = oracle.synthesize("gradient", unrolls=8, ports=1, max_states=1)
     assert not s.feasible and s.states_per_iter > 1
 
 
 def test_unknown_component_requires_fallback():
     sub, _ = _small()
-    oracle = PallasOracle(sub, timer=_fake_timer)
+    oracle = PallasOracle(sub, timer=_fake_timer, interpret=True)
     with pytest.raises(KeyError):
         oracle.synthesize("matrix_mul", unrolls=2, ports=1)
 
@@ -70,7 +71,7 @@ def test_unknown_component_requires_fallback():
 def test_ports_parallelism_and_area_economics():
     """More banks: lower per-bank latency, higher VMEM area (DESIGN.md §2)."""
     sub, _ = _small()
-    oracle = PallasOracle(sub, timer=_fake_timer)
+    oracle = PallasOracle(sub, timer=_fake_timer, interpret=True)
     s1 = oracle.synthesize("gradient", unrolls=4, ports=1)
     s4 = oracle.synthesize("gradient", unrolls=4, ports=4)
     assert s4.lam < s1.lam
@@ -90,7 +91,8 @@ def test_replay_is_byte_identical_to_fresh_record(tmp_path):
     path = str(tmp_path / "m.json")
 
     fresh = PallasOracle(sub, mode="record",
-                         store=MeasurementStore(path), timer=_fake_timer)
+                         store=MeasurementStore(path), timer=_fake_timer,
+                         interpret=True)
     r1 = cosmos_dse(tmg, fresh, spaces, delta=0.3)
     assert fresh.flush() == path
 
@@ -117,7 +119,7 @@ def test_record_resumes_without_retiming_paid_points(tmp_path):
 
     first = PallasOracle(sub, mode="record",
                          store=MeasurementStore(path, flush_every=1),
-                         timer=counting_timer)
+                         timer=counting_timer, interpret=True)
     first.synthesize("gradient", unrolls=4, ports=2)
     first.synthesize("gradient", unrolls=8, ports=2)
     first.synthesize("grayscale", unrolls=4, ports=1)
@@ -127,7 +129,7 @@ def test_record_resumes_without_retiming_paid_points(tmp_path):
     assert len(resumed_store) == 3
 
     second = PallasOracle(sub, mode="record", store=resumed_store,
-                          timer=counting_timer)
+                          timer=counting_timer, interpret=True)
     s = second.synthesize("gradient", unrolls=4, ports=2)    # paid already
     assert s.feasible and len(calls) == 3                    # not re-timed
     second.synthesize("gradient", unrolls=16, ports=2)       # new point
@@ -184,8 +186,8 @@ def test_checked_in_recording_drives_wami_end_to_end():
 def test_non_native_tile_routes_to_fallback():
     from repro.apps.wami.pipeline import wami_hls_tool
     sub, _ = _small()
-    oracle = PallasOracle(sub, timer=_fake_timer, native_tile=32,
-                          fallback=wami_hls_tool(tile=32))
+    oracle = PallasOracle(sub, timer=_fake_timer, interpret=True,
+                          native_tile=32, fallback=wami_hls_tool(tile=32))
     native = oracle.synthesize("gradient", unrolls=4, ports=2, tile=32)
     assert native.detail.get("wall_s") is not None       # measured path
     other = oracle.synthesize("gradient", unrolls=4, ports=2, tile=64)
@@ -197,7 +199,7 @@ def test_tile_request_without_native_tile_is_an_error():
     """An oracle with no declared native_tile cannot price a tile axis
     — doing so would relabel one tile's measurements as another's."""
     sub, _ = _small()
-    oracle = PallasOracle(sub, timer=_fake_timer)
+    oracle = PallasOracle(sub, timer=_fake_timer, interpret=True)
     with pytest.raises(ValueError, match="native_tile"):
         oracle.synthesize("gradient", unrolls=4, ports=2, tile=64)
 
@@ -424,3 +426,171 @@ def test_calibration_skips_bad_points():
                                             ("k", 1, 4, 4e-3)])
     assert fit.scale("k") == pytest.approx(1.0)   # only the 1x point fits
     assert fit.points["k"] == 1
+
+
+# ----------------------------------------------------------------------
+# device plumbing: where live measurements run, how they are keyed
+# ----------------------------------------------------------------------
+CHIP = "TPU v5 lite"
+
+
+def test_record_on_a_chip_kind_never_aliases_the_interpret_store(tmp_path):
+    from repro.core.pallas_oracle import open_recording
+    chip_path = default_measurement_path(128, CHIP)
+    assert chip_path != default_measurement_path(128)
+    assert chip_path.endswith("wami_pallas_tile128.tpu_v5_lite.json")
+    # a chip campaign refuses to resume an interpret file...
+    with pytest.raises(ValueError, match="needs a file of its own"):
+        open_recording(default_measurement_path(128), mode="record",
+                       tile=128, device_kind=CHIP)
+    # ...and a chip-keyed oracle over interpret stores finds no store,
+    # so it raises instead of writing chip walls under "interpret"
+    sub, _ = _small()
+    interp = MeasurementStore(str(tmp_path / "i.json"),
+                              meta={"tile": 32, "interpret": True})
+    oracle = PallasOracle(sub, mode="record", interpret=True,
+                          device_kind=CHIP, timer=_fake_timer,
+                          measurements=MeasurementSet().add(interp),
+                          native_tile=32)
+    with pytest.raises(MissingMeasurementError, match=CHIP):
+        oracle.synthesize("gradient", unrolls=4, ports=2)
+    assert len(interp) == 0
+    # a fresh chip file is tagged and keyed by the chip's kind
+    ms = open_recording(str(tmp_path / "c.json"), mode="record", tile=32,
+                        device_kind=CHIP)
+    assert ms.keys() == [(0, CHIP), (32, CHIP)]
+    chip = PallasOracle(sub, mode="record", interpret=True,
+                        device_kind=CHIP, timer=_fake_timer,
+                        measurements=ms, native_tile=32)
+    assert chip.synthesize("gradient", unrolls=4, ports=2).feasible
+    saved = MeasurementStore.load(chip.flush())
+    assert saved.device_kind == CHIP and len(saved) == 1
+
+
+def test_live_measurement_without_a_tpu_raises(tmp_path):
+    sub, _ = _small()
+    for mode in ("measure", "record"):
+        with pytest.raises(RuntimeError, match="needs a TPU"):
+            PallasOracle(sub, mode=mode, timer=_fake_timer,
+                         measurements=MeasurementSet.from_store(
+                             MeasurementStore(str(tmp_path / "m.json"))))
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        wami_pallas_oracle("record", tile=32,
+                           store_path=str(tmp_path / "w.json"))
+
+
+def test_record_mode_missing_store_raises_instead_of_falling_back(
+        tmp_path):
+    from repro.apps.wami.pipeline import wami_hls_tool
+    store = MeasurementStore(str(tmp_path / "t32.json"),
+                             meta={"tile": 32, "interpret": True})
+    oracle = PallasOracle(wami_pallas_components(32), mode="record",
+                          interpret=True, timer=_fake_timer,
+                          measurements=MeasurementSet().add(store),
+                          components_factory=wami_pallas_components,
+                          fallback=wami_hls_tool(tile=32), native_tile=32)
+    assert oracle.synthesize("gradient", unrolls=4, ports=2,
+                             tile=32).feasible
+    with pytest.raises(MissingMeasurementError, match="tile=64"):
+        oracle.synthesize("gradient", unrolls=4, ports=2, tile=64)
+    # a component without a kernel still prices analytically
+    assert oracle.synthesize("matrix_mul", unrolls=2, ports=1).feasible
+    assert oracle.stats["fallback"] == 0 and oracle.stats["timed"] == 1
+
+
+def test_replay_routes_to_interpret_recordings_on_any_host(monkeypatch):
+    """A TPU host replays the committed interpret recordings by default:
+    replay never asks the platform."""
+    import jax
+    from repro.core.registry import build_tool
+
+    class _Chip:
+        platform, device_kind = "tpu", CHIP
+
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: [_Chip()])
+    committed = MeasurementStore.load(default_measurement_path(128))
+    comp, ports, unrolls = min(committed.entries)
+    for oracle in (wami_pallas_oracle("replay"),
+                   build_tool("wami", "pallas")):
+        assert oracle.device_kind == "interpret"
+        s = oracle.synthesize(comp, unrolls=unrolls, ports=ports)
+        assert s.detail["wall_s"] == committed.get((comp, ports, unrolls))
+    mixed = MeasurementSet().add(committed).add(
+        MeasurementStore(meta={"tile": 128, "device_kind": CHIP}))
+    assert mixed.replay_kind() == "interpret"
+
+
+def test_vmem_budget_is_keyed_by_device_kind():
+    from repro.core.pallas_oracle import device_vmem_budget
+    assert device_vmem_budget(CHIP) == device_vmem_budget("interpret") \
+        == 16 * 1024 * 1024
+    with pytest.raises(ValueError, match="no VMEM budget"):
+        device_vmem_budget("TPU v99")
+    sub, _ = _small()
+    with pytest.raises(ValueError, match="no VMEM budget"):
+        PallasOracle(sub, interpret=True, device_kind="TPU v99",
+                     timer=_fake_timer)
+
+
+def test_compile_cache_dir_prefers_the_env_then_a_fixed_repo_path():
+    import os
+    from repro.launch.compile_cache import (REPO_CACHE_DIR,
+                                            compile_cache_dir)
+    assert compile_cache_dir({"JAX_COMPILATION_CACHE_DIR": "/c"}) == "/c"
+    assert compile_cache_dir({}) == compile_cache_dir({}) == REPO_CACHE_DIR
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert REPO_CACHE_DIR == os.path.join(repo, ".jax_cache")
+
+
+class _Refused:
+    """A program the compiler refuses at lowering, as Mosaic does."""
+
+    def __init__(self, exc):
+        self.exc = exc
+
+    def lower(self, *args):
+        raise self.exc
+
+
+def _spec_with(name, program):
+    from repro.core import PallasKernelSpec
+    from repro.kernels import wami_gradient
+    return PallasKernelSpec(name=name, shape=(32, 32),
+                            build=lambda p, u, interpret: program,
+                            vmem_bytes=wami_gradient.vmem_bytes,
+                            grid_steps=wami_gradient.grid_steps,
+                            n_in=4, n_out=2)
+
+
+def test_compiler_refusal_is_a_recorded_failed_synthesis(tmp_path):
+    import jax
+    import jax.numpy as jnp
+    refused = (_Refused(ValueError("block shape (2, 16) not tileable")), ())
+    fine = (jax.jit(lambda x: x + 1.0), (jnp.ones((8, 128)),))
+    specs = {"gradient": _spec_with("gradient", refused),
+             "grayscale": _spec_with("grayscale", fine)}
+    path = str(tmp_path / "m.json")
+    ledger = OracleLedger(PallasOracle(
+        specs, mode="record", interpret=True,
+        measurements=MeasurementSet.from_store(MeasurementStore(path))))
+    s = ledger.synthesize("gradient", unrolls=2, ports=2)
+    assert not s.feasible and s.detail["refused"].startswith(
+        "lowering: ValueError: block shape")
+    assert ledger.failed["gradient"] == 1          # counted like Fig. 11
+    ok = ledger.synthesize("grayscale", unrolls=2, ports=2)
+    assert ok.feasible and ok.detail["wall_s"] > 0
+    assert ledger.tool.stats["refused"] == 1 and ledger.tool.stats["timed"] == 1
+    ledger.tool.flush()
+    replay = PallasOracle(specs, mode="replay",
+                          measurements=MeasurementSet.from_store(
+                              MeasurementStore.load(path)))
+    again = replay.synthesize("gradient", unrolls=2, ports=2)
+    assert again.detail == s.detail and not again.feasible
+
+
+def test_other_exceptions_at_lowering_propagate():
+    boom = (_Refused(RuntimeError("not a compiler refusal")), ())
+    oracle = PallasOracle({"gradient": _spec_with("gradient", boom)},
+                          interpret=True)
+    with pytest.raises(RuntimeError, match="not a compiler refusal"):
+        oracle.synthesize("gradient", unrolls=2, ports=2)
