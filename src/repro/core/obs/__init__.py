@@ -21,7 +21,9 @@ phases, the oracle stack (:class:`~repro.core.oracle.OracleLedger` /
 carries an ``outcome`` tag from the four-way partition
 ``fresh | cache_hit | inflight_join | replay``),
 :meth:`~repro.core.plm.planner.PLMPlanner.plan_point` (certificate
-tier chosen), and the :class:`~repro.serve.dse_service.DSEService`
+tier chosen), the live measurements of
+:class:`~repro.core.pallas_oracle.PallasOracle` (``pallas.lower`` /
+``compile`` / ``warmup`` / ``reps``), and the :class:`~repro.serve.dse_service.DSEService`
 query lifecycle (submit -> queued -> dispatched -> done).
 """
 

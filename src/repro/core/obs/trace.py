@@ -20,7 +20,10 @@ makes every such event a first-class, exportable record:
   * two exporters — newline-JSON (:meth:`Tracer.export_jsonl`) for
     grep/jq pipelines, and the Chrome ``trace_event`` format
     (:meth:`Tracer.export_chrome`) so a full ``service-soak`` run opens
-    directly in Perfetto / ``chrome://tracing``.
+    directly in Perfetto / ``chrome://tracing``;
+  * ``Tracer(annotate=prefix)`` mirrors every context-managed span into
+    a ``jax.profiler.TraceAnnotation``, so the spans sit on the host
+    timeline of a profiler trace, on the device trace's clock.
 
 Tracing is opt-in and cheap when off: the module-level
 :data:`NULL_TRACER` satisfies the same surface with reused no-op
@@ -102,7 +105,8 @@ class Span:
     """
 
     __slots__ = ("_tracer", "name", "span_id", "parent_id", "tid",
-                 "start", "end", "attrs", "status", "error", "_stacked")
+                 "start", "end", "attrs", "status", "error", "_stacked",
+                 "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, span_id: int,
                  parent_id: Optional[int], tid: int, start: float,
@@ -118,30 +122,39 @@ class Span:
         self.status = "ok"
         self.error: Optional[str] = None
         self._stacked = False
+        self._annotation: Any = None
 
     def set(self, key: str, value: Any) -> None:
         """Attach/overwrite one attribute (JSON-able values only)."""
         self.attrs[key] = value
 
-    def finish(self, error: Optional[BaseException] = None) -> None:
+    def finish(self, error: Optional[BaseException] = None, *,
+               end: Optional[float] = None) -> None:
+        """Close the span at ``end`` (default: the tracer's clock now)."""
         if self.end is not None:      # idempotent
             return
         if error is not None:
             self.status = "error"
             self.error = f"{type(error).__name__}: {error}"
-        self._tracer._finish(self)
+        self._tracer._finish(self, end)
 
     # -- context manager ----------------------------------------------
     def __enter__(self) -> "Span":
         self._tracer._push(self)
         self._stacked = True
+        self._annotation = self._tracer._annotate(self)
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         if self._stacked:
             self._tracer._pop(self)
             self._stacked = False
-        self.finish(exc)
+        try:
+            self.finish(exc)
+        finally:
+            if self._annotation is not None:
+                self._annotation.__exit__(exc_type, exc, tb)
+                self._annotation = None
         return False                   # never swallow
 
     def to_json(self) -> Dict[str, Any]:
@@ -170,7 +183,8 @@ class _NullSpan:
     def set(self, key: str, value: Any) -> None:
         pass
 
-    def finish(self, error: Optional[BaseException] = None) -> None:
+    def finish(self, error: Optional[BaseException] = None, *,
+               end: Optional[float] = None) -> None:
         pass
 
     def __enter__(self) -> "_NullSpan":
@@ -196,10 +210,25 @@ class Tracer:
     small ints assigned in order of each thread's first span — under a
     sequential drive every run assigns the same lanes, which keeps
     logical-clock exports byte-stable.
+
+    ``annotate`` (a string) also writes each context-managed span into
+    the profiler's trace: entering the span enters
+    ``jax.profiler.TraceAnnotation(annotate + label)``, where ``label``
+    is the span's name, followed by its ``component`` attribute when it
+    has one (``"pallas.lower debayer"``).  Spans opened with
+    :meth:`begin` and closed with :meth:`Span.finish` are not mirrored:
+    a ``TraceAnnotation`` has to open and close on one thread, nested.
+    ``jax`` is imported only when ``annotate`` is set.
     """
 
-    def __init__(self, clock: Optional[Clock] = None):
+    def __init__(self, clock: Optional[Clock] = None,
+                 annotate: Optional[str] = None):
         self.clock = clock or WallClock()
+        self.annotate = annotate
+        self._profiler: Any = None
+        if annotate is not None:
+            import jax.profiler
+            self._profiler = jax.profiler
         self._lock = threading.Lock()
         self._spans: List[Span] = []
         self._next_id = 1
@@ -208,15 +237,16 @@ class Tracer:
 
     # -- span lifecycle ------------------------------------------------
     def span(self, name: str, *, parent: Optional[Span] = None,
-             **attrs: Any) -> Span:
+             start: Optional[float] = None, **attrs: Any) -> Span:
         """Open a span.  Use as ``with tracer.span(...) as sp:`` —
         entering pushes it onto this thread's parent stack."""
-        return self.begin(name, parent=parent, **attrs)
+        return self.begin(name, parent=parent, start=start, **attrs)
 
     def begin(self, name: str, *, parent: Optional[Span] = None,
-              **attrs: Any) -> Span:
+              start: Optional[float] = None, **attrs: Any) -> Span:
         """Open a span without touching the parent stack (for
-        lifecycles finished elsewhere via :meth:`Span.finish`)."""
+        lifecycles finished elsewhere via :meth:`Span.finish`).
+        ``start`` is a time the caller already read off the clock."""
         ident = threading.get_ident()
         with self._lock:
             span_id = self._next_id
@@ -228,7 +258,8 @@ class Tracer:
                 parent = stack[-1]
         parent_id = None if parent is None else parent.span_id
         return Span(self, name, span_id, parent_id, tid,
-                    self.clock.now(), dict(attrs))
+                    self.clock.now() if start is None else start,
+                    dict(attrs))
 
     def instant(self, name: str, *, parent: Optional[Span] = None,
                 **attrs: Any) -> None:
@@ -250,10 +281,22 @@ class Tracer:
         if stack and stack[-1] is span:
             stack.pop()
 
-    def _finish(self, span: Span) -> None:
-        span.end = self.clock.now()
+    def _finish(self, span: Span, end: Optional[float]) -> None:
+        span.end = self.clock.now() if end is None else end
         with self._lock:
             self._spans.append(span)
+
+    def _annotate(self, span: Span) -> Any:
+        """The entered profiler annotation mirroring ``span`` (None when
+        this tracer does not annotate)."""
+        if self._profiler is None:
+            return None
+        label = span.name
+        if "component" in span.attrs:
+            label += f" {span.attrs['component']}"
+        annotation = self._profiler.TraceAnnotation(self.annotate + label)
+        annotation.__enter__()
+        return annotation
 
     def current(self) -> Optional[Span]:
         """This thread's innermost open span (None outside any)."""
@@ -328,6 +371,7 @@ class NullTracer(Tracer):
         pass
 
     def span(self, name: str, *, parent: Optional[Span] = None,
+             start: Optional[float] = None,
              **attrs: Any) -> _NullSpan:        # type: ignore[override]
         return _NULL_SPAN
 

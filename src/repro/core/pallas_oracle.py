@@ -50,12 +50,12 @@ import json
 import os
 import re
 import threading
-import time
 import warnings
 from dataclasses import dataclass
 from typing import (Any, Callable, Dict, Iterable, List, Optional, Tuple)
 
 from .knobs import CDFGFacts, Synthesis, SynthesisTool
+from .obs import WallClock
 from .oracle import OracleBatchMixin, call_synthesize
 
 __all__ = [
@@ -541,11 +541,14 @@ class PallasOracle(OracleBatchMixin):
         # or the compiler's refusal reason (a str)
         self._measured: Dict[Tuple[str, int, int, int], Any] = {}
         # what the live measurements cost: points timed / refused,
-        # seconds spent compiling vs. in the timed reps, and kernel
-        # components the fallback tool priced instead
+        # seconds spent lowering and compiling (``compile_s``, of which
+        # ``lower_s`` lowering) vs. in the timed reps, compiles the
+        # persistent cache served or missed, and kernel components the
+        # fallback tool priced instead
         self.stats: Dict[str, float] = {"timed": 0, "refused": 0,
                                         "fallback": 0, "compile_s": 0.0,
-                                        "timed_s": 0.0}
+                                        "lower_s": 0.0, "timed_s": 0.0,
+                                        "cache_hits": 0, "cache_misses": 0}
         self._lock = threading.Lock()
         # timing under a thread-pool fan-out measures contention, not the
         # kernel: _measure_lock serializes every real measurement even
@@ -602,35 +605,64 @@ class PallasOracle(OracleBatchMixin):
     # ------------------------------------------------------------------
     # measurement
     # ------------------------------------------------------------------
-    def _time_program(self, program: Any, args: Tuple[Any, ...]) -> Any:
+    def _time_program(self, program: Any, args: Tuple[Any, ...],
+                      **attrs: Any) -> Any:
         """Compile ``program`` for ``args``, warm it up, and return the
         best of ``reps`` timed launches (seconds), each ending in
         ``block_until_ready``.  A kernel the TPU compiler refuses — at
         lowering or at compile time — returns the refusal reason (a
-        str) instead; any other exception propagates."""
+        str) instead; any other exception propagates.
+
+        Each stage is a span on :attr:`tracer` (``pallas.lower``,
+        ``pallas.compile``, ``pallas.warmup``, ``pallas.reps``, carrying
+        ``attrs``), and the same clock reads feed :attr:`stats`."""
         import jax
         from jax.experimental.pallas import tpu as pltpu
-        t0 = time.perf_counter()
-        try:
-            lowered = program.lower(*args)
-        except (ValueError, NotImplementedError,
-                pltpu.LoweringException) as e:
-            return _refusal("lowering", e)
-        try:
-            compiled = lowered.compile()
-        except jax.errors.JaxRuntimeError as e:
-            return _refusal("compile", e)
-        compile_s = time.perf_counter() - t0
-        jax.block_until_ready(compiled(*args))           # warm-up
-        best = float("inf")
-        t_reps = time.perf_counter()
-        for _ in range(self.reps):
-            t0 = time.perf_counter()
-            jax.block_until_ready(compiled(*args))
-            best = min(best, time.perf_counter() - t0)
+        from ..launch.compile_cache import cache_outcome, compile_events
+        events = compile_events()
+        before = events.snapshot()
+        with _Stage(self.tracer, "pallas.lower", _CLOCK.now(),
+                    attrs) as lower:
+            try:
+                lowered = program.lower(*args)
+            except (ValueError, NotImplementedError,
+                    pltpu.LoweringException) as e:
+                lowered = _refusal("lowering", e)
+                lower.span.set("refused", lowered)
+            after = events.snapshot()
+            for key in ("trace_s", "mlir_s"):
+                lower.span.set(key, after[key] - before[key])
+        if isinstance(lowered, str):
+            return lowered
+        with _Stage(self.tracer, "pallas.compile", lower.end,
+                    attrs) as comp:
+            before = events.snapshot()
+            try:
+                compiled = lowered.compile()
+            except jax.errors.JaxRuntimeError as e:
+                compiled = _refusal("compile", e)
+                comp.span.set("refused", compiled)
+            cache = cache_outcome(before, events.snapshot())
+            comp.span.set("cache", cache)
         with self._lock:
-            self.stats["compile_s"] += compile_s
-            self.stats["timed_s"] += time.perf_counter() - t_reps
+            self.stats["cache_hits"] += cache == "hit"
+            self.stats["cache_misses"] += cache == "miss"
+        if isinstance(compiled, str):
+            return compiled
+        with _Stage(self.tracer, "pallas.warmup", comp.end, attrs) as warm:
+            jax.block_until_ready(compiled(*args))
+        best = float("inf")
+        with _Stage(self.tracer, "pallas.reps", warm.end, attrs) as reps:
+            t = _CLOCK.now()
+            for _ in range(self.reps):
+                jax.block_until_ready(compiled(*args))
+                t, t0 = _CLOCK.now(), t
+                best = min(best, t - t0)
+            reps.span.set("best_s", best)
+        with self._lock:
+            self.stats["lower_s"] += lower.seconds
+            self.stats["compile_s"] += comp.end - lower.start
+            self.stats["timed_s"] += reps.seconds
         return best
 
     def _missing_error(self, key: MeasureKey, resolved: int
@@ -679,7 +711,8 @@ class PallasOracle(OracleBatchMixin):
                     wall = float(self.timer(spec.name, ports, unrolls,
                                             built))
                 else:
-                    wall = self._time_program(*built)
+                    wall = self._time_program(*built, component=spec.name,
+                                              ports=ports, unrolls=unrolls)
                 with self._lock:
                     self.stats["refused" if isinstance(wall, str)
                                else "timed"] += 1
@@ -833,6 +866,36 @@ class PallasOracle(OracleBatchMixin):
         if native is not None and native.path in saved:
             return native.path
         return saved[0] if saved else None
+
+
+_CLOCK = WallClock()
+
+
+class _Stage:
+    """One stage of a live measurement: a span on the oracle's tracer
+    whose two ends are the clock reads the oracle's counters take.
+    Opened at ``start`` (the previous stage's end), closed at a read
+    taken on exit."""
+
+    __slots__ = ("span", "start", "end")
+
+    def __init__(self, tracer: Any, name: str, start: float,
+                 attrs: Dict[str, Any]):
+        self.start = self.end = start
+        self.span = tracer.span(name, start=start, **attrs)
+
+    @property
+    def seconds(self) -> float:
+        return self.end - self.start
+
+    def __enter__(self) -> "_Stage":
+        self.span.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self.end = _CLOCK.now()
+        self.span.finish(exc, end=self.end)
+        return self.span.__exit__(exc_type, exc, tb)
 
 
 def _refusal(phase: str, exc: Exception) -> str:

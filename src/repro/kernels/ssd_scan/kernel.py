@@ -127,5 +127,6 @@ def ssd_scan(x, dt, A, B, C, *, chunk: int = 128, interpret: bool = False):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="ssd_scan",
     )(xt, dt_col, dt_row, a3, B, C)
     return y.transpose(0, 2, 1, 3), h_fin

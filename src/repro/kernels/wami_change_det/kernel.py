@@ -81,7 +81,8 @@ def change_detection_kernel(gray: jnp.ndarray, mu: jnp.ndarray,
     planes = jnp.concatenate([gray[..., None], mu, var, w], axis=-1)
     out = banked_call(
         functools.partial(_kernel, lr=lr, mahal=mahal_thresh, fg=fg_thresh),
-        planes, _N_OUT, ports=ports, unrolls=unrolls, interpret=interpret)
+        planes, _N_OUT, name="change_det", ports=ports, unrolls=unrolls,
+        interpret=interpret)
     return (out[..., 0], out[..., 1:1 + _K], out[..., 1 + _K:1 + 2 * _K],
             out[..., 1 + 2 * _K:])
 

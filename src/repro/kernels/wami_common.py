@@ -69,13 +69,16 @@ def bank_spec(channels: int, bh: int, bw: int) -> pl.BlockSpec:
 
 
 def banked_call(body: Callable, planes: jnp.ndarray, n_out: int, *,
-                ports: int, unrolls: int, interpret: bool,
+                name: str, ports: int, unrolls: int, interpret: bool,
                 out_dtype: Optional[jnp.dtype] = None) -> jnp.ndarray:
     """Run ``body(in_ref, out_ref)`` over the (H/unrolls, ports) knob
     grid.  ``planes`` is the stage's (H, W, C) input stack; the kernel
     sees a (C, unrolls, W/ports) input block and writes an (n_out,
     unrolls, W/ports) output block.  Returns the (H, W, n_out) stack.
-    Both grid axes are independent (elementwise/stencil stages)."""
+    Both grid axes are independent (elementwise/stencil stages).
+    ``name`` names the ``pallas_call`` (the registry's component name),
+    so its device op keeps that name whatever the Python helper is
+    called."""
     H, W, C = planes.shape
     bh, bw = knob_blocks(H, W, ports=ports, unrolls=unrolls)
     out = pl.pallas_call(
@@ -88,6 +91,7 @@ def banked_call(body: Callable, planes: jnp.ndarray, n_out: int, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
+        name=name,
     )(to_banks(planes, ports=ports, unrolls=unrolls))
     return from_banks(out)
 

@@ -64,7 +64,8 @@ def debayer_kernel(bayer: jnp.ndarray, *, ports: int = 1, unrolls: int = 8,
              p[:-2, :-2], p[:-2, 2:],                    # nw, ne
              p[2:, :-2], p[2:, 2:])                      # sw, se
     return banked_call(_kernel, jnp.stack(views, axis=-1), _N_OUT,
-                       ports=ports, unrolls=unrolls, interpret=interpret)
+                       name="debayer", ports=ports, unrolls=unrolls,
+                       interpret=interpret)
 
 
 vmem_bytes = functools.partial(vmem_bytes_model, n_in=_N_IN, n_out=_N_OUT)

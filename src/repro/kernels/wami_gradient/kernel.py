@@ -43,8 +43,8 @@ def gradient_kernel(gray: jnp.ndarray, *, ports: int = 1, unrolls: int = 8,
     H % unrolls == 0.  Returns (gx, gy)."""
     p = jnp.pad(gray, 1, mode="edge")
     views = (p[1:-1, :-2], p[1:-1, 2:], p[:-2, 1:-1], p[2:, 1:-1])
-    g = banked_call(_kernel, jnp.stack(views, axis=-1), 2, ports=ports,
-                    unrolls=unrolls, interpret=interpret)
+    g = banked_call(_kernel, jnp.stack(views, axis=-1), 2, name="gradient",
+                    ports=ports, unrolls=unrolls, interpret=interpret)
     return g[..., 0], g[..., 1]
 
 

@@ -26,8 +26,8 @@ def _kernel(rgb_ref, y_ref):
 def grayscale_kernel(rgb: jnp.ndarray, *, ports: int = 1, unrolls: int = 8,
                      interpret: bool = False) -> jnp.ndarray:
     """rgb: (H, W, 3) with W % ports == 0 and H % unrolls == 0 -> (H, W)."""
-    return banked_call(_kernel, rgb, 1, ports=ports, unrolls=unrolls,
-                       interpret=interpret)[..., 0]
+    return banked_call(_kernel, rgb, 1, name="grayscale", ports=ports,
+                       unrolls=unrolls, interpret=interpret)[..., 0]
 
 
 vmem_bytes = functools.partial(vmem_bytes_model, n_in=_N_IN, n_out=_N_OUT)

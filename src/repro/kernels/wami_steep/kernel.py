@@ -56,7 +56,8 @@ def steepest_descent_kernel(gx: jnp.ndarray, gy: jnp.ndarray, *,
                             interpret: bool = False) -> jnp.ndarray:
     """gx, gy: (H, W) image gradients -> sd images (H, W, 6)."""
     return banked_call(_sd_kernel, jnp.stack([gx, gy], axis=-1), _N_OUT,
-                       ports=ports, unrolls=unrolls, interpret=interpret)
+                       name="steep_descent", ports=ports, unrolls=unrolls,
+                       interpret=interpret)
 
 
 def _hessian_kernel(sd_ref, out_ref):
@@ -90,6 +91,7 @@ def hessian_kernel(sd: jnp.ndarray, *, ports: int = 1, unrolls: int = 8,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
+        name="hessian",
     )(flat)
 
 

@@ -55,8 +55,9 @@ def warp_blend_kernel(img: jnp.ndarray, p: jnp.ndarray, *, ports: int = 1,
                       ) -> jnp.ndarray:
     """img: (H, W), p: affine params (6,) -> warped (H, W)."""
     planes = jnp.stack(warp_gather(img, p), axis=-1)
-    return banked_call(_kernel, planes, 1, ports=ports, unrolls=unrolls,
-                       interpret=interpret, out_dtype=img.dtype)[..., 0]
+    return banked_call(_kernel, planes, 1, name="warp", ports=ports,
+                       unrolls=unrolls, interpret=interpret,
+                       out_dtype=img.dtype)[..., 0]
 
 
 vmem_bytes = functools.partial(vmem_bytes_model, n_in=_N_IN, n_out=_N_OUT)

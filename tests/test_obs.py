@@ -125,6 +125,69 @@ def test_null_tracer_is_inert():
     NULL_TRACER.instant("evt")
 
 
+def test_span_takes_times_read_by_its_caller():
+    tr = Tracer(clock=LogicalClock())
+    with tr.span("stage", start=0.25) as sp:
+        sp.finish(end=0.75)
+    [s] = tr.spans("stage")
+    assert (s.start, s.end, s.status) == (0.25, 0.75, "ok")
+    assert tr.current() is None
+
+
+class _Annotations:
+    """Stands in for ``jax.profiler.TraceAnnotation``: logs enter/exit."""
+
+    def __init__(self):
+        self.log = []
+        outer = self
+
+        class Annotation:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                outer.log.append(("enter", self.name))
+
+            def __exit__(self, *exc):
+                outer.log.append(("exit", self.name))
+        self.cls = Annotation
+
+
+def test_annotating_tracer_mirrors_each_context_managed_span(monkeypatch):
+    import jax.profiler
+    ann = _Annotations()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", ann.cls)
+    tr = Tracer(annotate="x:")
+    with tr.span("session.plan", delta=0.25):
+        with tr.span("pallas.lower", component="debayer", ports=2):
+            pass
+        tr.begin("service.query", component="c").finish()
+        tr.instant("session.progress", component="c")
+    with pytest.raises(RuntimeError):
+        with tr.span("tool.point", component="warp"):
+            raise RuntimeError("seeded")
+    assert ann.log == [("enter", "x:session.plan"),
+                       ("enter", "x:pallas.lower debayer"),
+                       ("exit", "x:pallas.lower debayer"),
+                       ("exit", "x:session.plan"),
+                       ("enter", "x:tool.point warp"),
+                       ("exit", "x:tool.point warp")]
+    assert [s.name for s in tr.spans()] == [
+        "session.plan", "pallas.lower", "service.query", "session.progress",
+        "tool.point"]
+
+
+def test_default_tracer_annotates_nothing(monkeypatch):
+    import jax.profiler
+    ann = _Annotations()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", ann.cls)
+    _, tr = _traced_run(Tracer())
+    assert tr.spans() and ann.log == []
+    with NULL_TRACER.span("pallas.lower", component="debayer"):
+        pass
+    assert ann.log == []
+
+
 def test_exports_are_valid_and_schema_checked():
     _, tr = _traced_run()
     assert validate_jsonl(tr.export_jsonl()) == []

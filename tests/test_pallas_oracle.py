@@ -1,6 +1,7 @@
 """PallasOracle semantics: feasibility, accounting, record/replay
 determinism, fallback routing, and calibration."""
 
+import contextlib
 import math
 
 import pytest
@@ -594,3 +595,179 @@ def test_other_exceptions_at_lowering_propagate():
                           interpret=True)
     with pytest.raises(RuntimeError, match="not a compiler refusal"):
         oracle.synthesize("gradient", unrolls=2, ports=2)
+
+
+# ----------------------------------------------------------------------
+# the measurement's spans and counters
+# ----------------------------------------------------------------------
+PALLAS_SPANS = ("pallas.lower", "pallas.compile", "pallas.warmup",
+                "pallas.reps")
+
+
+def _live_specs():
+    import jax
+    import jax.numpy as jnp
+    refused = (_Refused(ValueError("block shape (2, 16) not tileable")), ())
+    return {"gradient": _spec_with("gradient", refused),
+            "grayscale": _spec_with("grayscale", (
+                jax.jit(lambda x: x * 2.0 + 1.0), (jnp.ones((8, 128)),))),
+            "warp": _spec_with("warp", (
+                jax.jit(lambda x: jnp.tanh(x) - x), (jnp.ones((8, 128)),)))}
+
+
+def _live_traced_ledger():
+    from repro.core import Tracer, WallClock
+    tracer = Tracer(WallClock())
+    ledger = OracleLedger(PallasOracle(_live_specs(), interpret=True),
+                          tracer=tracer)
+    for name in ("gradient", "grayscale", "warp"):
+        ledger.synthesize(name, unrolls=2, ports=2)
+    return ledger.tool, tracer
+
+
+def test_each_timed_point_has_one_span_per_stage_inside_its_tool_point():
+    oracle, tracer = _live_traced_ledger()
+    points = {s.attrs["component"]: s for s in tracer.spans("tool.point")}
+    for name in ("grayscale", "warp"):
+        kids = [s for s in tracer.spans() if s.parent_id
+                == points[name].span_id and s.name.startswith("pallas.")]
+        assert [s.name for s in kids] == list(PALLAS_SPANS)
+        for s in kids:
+            assert s.attrs["component"] == name
+            assert (s.attrs["ports"], s.attrs["unrolls"]) == (2, 2)
+        lower, comp, warm, reps = kids
+        # one clock: each stage opens where the previous one closed
+        assert lower.end == comp.start and comp.end == warm.start \
+            and warm.end == reps.start
+        assert "refused" not in lower.attrs and "refused" not in comp.attrs
+        assert comp.attrs["cache"] in ("off", "hit", "miss")
+        assert 0 < reps.attrs["best_s"] <= reps.end - reps.start
+    assert oracle.stats["timed"] == 2
+
+
+def test_lowering_phases_never_exceed_the_lower_span():
+    _, tracer = _live_traced_ledger()
+    lowers = tracer.spans("pallas.lower")
+    assert len(lowers) == 3
+    for s in lowers:
+        assert s.attrs["trace_s"] >= 0 and s.attrs["mlir_s"] >= 0
+        assert s.attrs["trace_s"] + s.attrs["mlir_s"] <= s.end - s.start
+    timed = [s for s in lowers if "refused" not in s.attrs]
+    assert all(s.attrs["trace_s"] > 0 and s.attrs["mlir_s"] > 0
+               for s in timed)
+
+
+def test_a_point_refused_at_lowering_has_only_its_lower_span():
+    _, tracer = _live_traced_ledger()
+    [point] = [s for s in tracer.spans("tool.point")
+               if s.attrs["component"] == "gradient"]
+    kids = [s for s in tracer.spans() if s.parent_id == point.span_id]
+    assert [s.name for s in kids] == ["pallas.lower"]
+    assert kids[0].attrs["refused"].startswith(
+        "lowering: ValueError: block shape")
+
+
+def test_counters_share_the_spans_clock_reads():
+    oracle, tracer = _live_traced_ledger()
+    st = oracle.stats
+
+    def total(name):
+        return sum(s.end - s.start for s in tracer.spans(name)
+                   if "refused" not in s.attrs)
+    assert 0 < st["lower_s"] <= st["compile_s"]
+    assert st["lower_s"] == pytest.approx(total("pallas.lower"))
+    assert st["compile_s"] == pytest.approx(total("pallas.lower")
+                                            + total("pallas.compile"))
+    assert st["timed_s"] == pytest.approx(total("pallas.reps"))
+
+
+@contextlib.contextmanager
+def _compile_cache_at(path):
+    """JAX's persistent compilation cache in ``path`` (None: no cache)
+    for one test, with no floor on what it keeps; JAX's own settings
+    come back after."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    keep = {k: getattr(jax.config, k) for k in (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs")}
+    jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        for k, v in keep.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def persistent_cache(tmp_path):
+    with _compile_cache_at(str(tmp_path / "cache")):
+        yield
+
+
+def test_cache_hits_and_misses_count_the_compiles_that_asked_the_cache(
+        persistent_cache):
+    import jax
+    import jax.numpy as jnp
+    from repro.core import Tracer, WallClock
+    tracer = Tracer(WallClock())
+    stats = []
+    for _ in range(2):               # a new program each time, same HLO
+        spec = _spec_with("grayscale", (jax.jit(lambda x: x * 3.0 - 1.0),
+                                        (jnp.ones((8, 128)),)))
+        oracle = PallasOracle({"grayscale": spec}, interpret=True)
+        OracleLedger(oracle, tracer=tracer).synthesize(
+            "grayscale", unrolls=2, ports=2)
+        stats.append(oracle.stats)
+    assert [s.attrs["cache"] for s in tracer.spans("pallas.compile")] \
+        == ["miss", "hit"]
+    assert [(s["cache_hits"], s["cache_misses"]) for s in stats] \
+        == [(0, 1), (1, 0)]
+
+
+def test_without_a_persistent_cache_every_compile_reads_off():
+    with _compile_cache_at(None):
+        oracle, tracer = _live_traced_ledger()
+    assert {s.attrs["cache"] for s in tracer.spans("pallas.compile")} \
+        == {"off"}
+    assert oracle.stats["cache_hits"] == oracle.stats["cache_misses"] == 0
+
+
+def test_untraced_injected_timer_opens_no_span_and_keeps_the_walls():
+    from repro.core import NULL_TRACER, Tracer, WallClock
+    sub, _ = _small()
+    plain = PallasOracle(sub, timer=_fake_timer, interpret=True)
+    assert plain.tracer is NULL_TRACER
+    tracer = Tracer(WallClock())
+    traced = OracleLedger(PallasOracle(sub, timer=_fake_timer,
+                                       interpret=True), tracer=tracer)
+    for p, u in ((1, 8), (2, 4), (4, 2)):
+        a = plain.synthesize("gradient", unrolls=u, ports=p)
+        b = traced.synthesize("gradient", unrolls=u, ports=p)
+        assert a.detail["wall_s"] == b.detail["wall_s"] \
+            == _fake_timer("gradient", p, u, None)
+    assert NULL_TRACER.spans() == []
+    assert not [s for s in tracer.spans() if s.name.startswith("pallas.")]
+    assert plain.stats["compile_s"] == plain.stats["lower_s"] == 0
+
+
+def test_compile_events_count_only_the_outermost_lowering_phase():
+    from repro.launch.compile_cache import CompileEvents
+    trace = "/jax/core/compile/jaxpr_trace_duration"
+    mlir = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+    ev = CompileEvents()
+    ev._phase_end(trace, 0.0, 0.5)         # opened before the listener
+    ev._phase_start(trace, 10.0)
+    ev._phase_start(trace, 11.0)           # a helper traced inside
+    ev._phase_end(trace, 11.0, 12.0)
+    ev._phase_end(trace, 10.0, 13.0)
+    ev._phase_start(mlir, 20.0)
+    ev._phase_start(trace, 21.0)           # tracing inside MLIR lowering
+    ev._phase_end(trace, 21.0, 22.0)
+    ev._phase_end(mlir, 20.0, 24.0)
+    ev._phase_start("/jax/other", 1.0)     # other events are ignored
+    snap = ev.snapshot()
+    assert (snap["trace_s"], snap["mlir_s"]) == (0.5 + 3.0, 4.0)
