@@ -53,6 +53,7 @@ from ...core.tmg import TMG, pipeline_tmg
 from ...core.xlatool import XLATool
 from ...kernels.flash_attention import mha, mha_ref
 from ...kernels.ssd_scan import ssd, ssd_oracle
+from ...launch.compile_cache import PointProgram
 
 __all__ = ["FLASH_S", "FLASH_D", "FLASH_HEADS", "SSD_S", "SSD_P", "SSD_N",
            "SSD_MAX_HEADS", "fleet_tmg", "fleet_knob_spaces",
@@ -174,15 +175,15 @@ def fleet_kernel_specs(tile: int = 0) -> Dict[str, PallasKernelSpec]:
     q, k, v, x, dt, A, Bm, Cm = _fleet_inputs()
 
     def build_flash(ports: int, unrolls: int, interpret: bool):
-        return jax.jit(functools.partial(
+        return PointProgram(jax.jit(functools.partial(
             mha, causal=True, block_q=FLASH_S // ports,
             block_kv=_flash_block_kv(unrolls), use_pallas=True,
-            interpret=interpret)), (q, k, v)
+            interpret=interpret))), (q, k, v)
 
     def build_ssd(ports: int, unrolls: int, interpret: bool):
-        return jax.jit(functools.partial(
+        return PointProgram(jax.jit(functools.partial(
             ssd, chunk=_ssd_chunk(unrolls), use_pallas=True,
-            interpret=interpret)), (x[:, :, :ports, :], dt[:, :, :ports],
+            interpret=interpret))), (x[:, :, :ports, :], dt[:, :, :ports],
                                     A[:ports], Bm, Cm)
 
     return {

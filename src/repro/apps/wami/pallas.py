@@ -43,6 +43,7 @@ from ...core.pallas_oracle import (MeasurementSet, MeasurementStore,
 from ...core.plm.units import UnitSystem, fit_unit_system
 from ...core.registry import App, build_session, register_app
 from ...core.session import ExplorationSession
+from ...launch.compile_cache import PointProgram
 from ...kernels import (wami_change_det, wami_debayer, wami_gradient,
                         wami_grayscale, wami_steep, wami_warp)
 from . import components as C
@@ -102,9 +103,9 @@ def wami_pallas_components(tile: int = C.TILE
 
     def bake(fn: Callable, *args) -> Callable:
         def build(ports: int, unrolls: int, interpret: bool):
-            return jax.jit(functools.partial(
+            return PointProgram(jax.jit(functools.partial(
                 fn, ports=ports, unrolls=unrolls, use_pallas=True,
-                interpret=interpret)), args
+                interpret=interpret))), args
         return build
 
     shape = (tile, tile)

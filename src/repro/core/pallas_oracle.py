@@ -146,7 +146,9 @@ class PallasKernelSpec:
 
     ``build(ports, unrolls, interpret)`` returns ``(program, args)``: a
     ``jax.jit``-compiled program covering the ops wrapper and the
-    ``pallas_call``, and its baked deterministic inputs.  The oracle
+    ``pallas_call`` (the apps wrap it in a
+    :class:`~repro.launch.compile_cache.PointProgram`), and its baked
+    deterministic inputs.  The oracle
     lowers and compiles ``program`` for ``args`` apart from the timed
     reps, then times ``compiled(*args)`` launches.
     ``vmem_bytes``/``grid_steps`` are the kernel package's cost models
@@ -615,10 +617,15 @@ class PallasOracle(OracleBatchMixin):
 
         Each stage is a span on :attr:`tracer` (``pallas.lower``,
         ``pallas.compile``, ``pallas.warmup``, ``pallas.reps``, carrying
-        ``attrs``), and the same clock reads feed :attr:`stats`."""
+        ``attrs``), and the same clock reads feed :attr:`stats`.  A
+        program whose executable the point cache holds
+        (:class:`~repro.launch.compile_cache.PointProgram`) lowers
+        nothing: its ``pallas.lower`` reads ``point_cache="hit"`` and its
+        compile counts as a persistent-cache hit."""
         import jax
         from jax.experimental.pallas import tpu as pltpu
-        from ..launch.compile_cache import cache_outcome, compile_events
+        from ..launch.compile_cache import (cache_outcome, compile_events,
+                                            point_outcome)
         events = compile_events()
         before = events.snapshot()
         with _Stage(self.tracer, "pallas.lower", _CLOCK.now(),
@@ -632,6 +639,8 @@ class PallasOracle(OracleBatchMixin):
             after = events.snapshot()
             for key in ("trace_s", "mlir_s"):
                 lower.span.set(key, after[key] - before[key])
+            point = point_outcome(before, after)
+            lower.span.set("point_cache", point)
         if isinstance(lowered, str):
             return lowered
         with _Stage(self.tracer, "pallas.compile", lower.end,
@@ -643,6 +652,8 @@ class PallasOracle(OracleBatchMixin):
                 compiled = _refusal("compile", e)
                 comp.span.set("refused", compiled)
             cache = cache_outcome(before, events.snapshot())
+            if point == "hit" and cache == "off":
+                cache = "hit"      # the executable came from the point entry
             comp.span.set("cache", cache)
         with self._lock:
             self.stats["cache_hits"] += cache == "hit"

@@ -11,6 +11,7 @@ replaced with a clean ``pytest.skip`` and the rest of each module runs
 normally.  ``pip install -e .[test]`` restores the real property tests.
 """
 
+import contextlib
 import os
 import sys
 
@@ -62,6 +63,44 @@ def committed_artifact():
             return f.read()
 
     return _read
+
+
+@contextlib.contextmanager
+def _compile_cache_at(path):
+    """JAX's persistent compilation cache in ``path`` (None: no cache)
+    for one test, with no floor on what it keeps; JAX's own settings
+    come back after."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    keep = {k: getattr(jax.config, k) for k in (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs")}
+    jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        for k, v in keep.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def compile_cache_at():
+    """``with compile_cache_at(path):`` — JAX's persistent cache in
+    ``path`` (None: none) for the block."""
+    return _compile_cache_at
+
+
+@pytest.fixture
+def persistent_cache(tmp_path):
+    """JAX's persistent cache in a new directory for the whole test;
+    yields that directory."""
+    path = str(tmp_path / "cache")
+    with _compile_cache_at(path):
+        yield path
+
 
 try:
     import hypothesis  # noqa: F401

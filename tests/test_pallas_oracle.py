@@ -1,7 +1,6 @@
 """PallasOracle semantics: feasibility, accounting, record/replay
 determinism, fallback routing, and calibration."""
 
-import contextlib
 import math
 
 import pytest
@@ -681,33 +680,6 @@ def test_counters_share_the_spans_clock_reads():
     assert st["timed_s"] == pytest.approx(total("pallas.reps"))
 
 
-@contextlib.contextmanager
-def _compile_cache_at(path):
-    """JAX's persistent compilation cache in ``path`` (None: no cache)
-    for one test, with no floor on what it keeps; JAX's own settings
-    come back after."""
-    import jax
-    from jax.experimental.compilation_cache import compilation_cache
-    keep = {k: getattr(jax.config, k) for k in (
-        "jax_compilation_cache_dir",
-        "jax_persistent_cache_min_compile_time_secs")}
-    jax.config.update("jax_compilation_cache_dir", path)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    compilation_cache.reset_cache()
-    try:
-        yield
-    finally:
-        for k, v in keep.items():
-            jax.config.update(k, v)
-        compilation_cache.reset_cache()
-
-
-@pytest.fixture
-def persistent_cache(tmp_path):
-    with _compile_cache_at(str(tmp_path / "cache")):
-        yield
-
-
 def test_cache_hits_and_misses_count_the_compiles_that_asked_the_cache(
         persistent_cache):
     import jax
@@ -728,8 +700,9 @@ def test_cache_hits_and_misses_count_the_compiles_that_asked_the_cache(
         == [(0, 1), (1, 0)]
 
 
-def test_without_a_persistent_cache_every_compile_reads_off():
-    with _compile_cache_at(None):
+def test_without_a_persistent_cache_every_compile_reads_off(
+        compile_cache_at):
+    with compile_cache_at(None):
         oracle, tracer = _live_traced_ledger()
     assert {s.attrs["cache"] for s in tracer.spans("pallas.compile")} \
         == {"off"}
