@@ -22,7 +22,9 @@ which asserts (a) the COSMOS front matches the exhaustively composed
 front at its extremes and stays within the paper's mapping bound
 everywhere, and (b) COSMOS still beats the exhaustive baseline on
 oracle invocations (reduction >= 1) with the Fig. 11 ledger counting
-across both stages.  ``--record`` re-measures the kernel recording.
+across both stages.  ``--record`` re-measures the kernel recording;
+``--record --app fleet-zamba2-7b`` records the full-size Zamba2-7B
+geometry's (on a TPU host only).
 """
 
 from __future__ import annotations
@@ -139,18 +141,20 @@ def smoke(backend: str = "analytical") -> int:
     return 0 if ok else 1
 
 
-def record() -> int:
-    """Re-measure the fleet kernel recording by driving the exact
+def record(app: str = "fleet") -> int:
+    """Re-measure a fleet app's kernel recording by driving the exact
     session the replay backend reproduces: the interpreter on a CPU
     host, the compiled kernels on a TPU host, each device kind into its
     own file."""
-    from repro.apps.fleet import fleet_pallas_oracle
+    from repro.apps.fleet import FLEET, ZAMBA2_7B_TP4, fleet_pallas_oracle
     from repro.core.pallas_oracle import platform_interpret
     from repro.core.registry import build_session
     from repro.launch.compile_cache import enable_compile_cache
+    geometry = {g.app: g for g in (FLEET, ZAMBA2_7B_TP4)}[app]
     enable_compile_cache()
-    oracle = fleet_pallas_oracle("record", interpret=platform_interpret())
-    res = build_session("fleet", "pallas", tool=oracle, workers=1).run()
+    oracle = fleet_pallas_oracle("record", interpret=platform_interpret(),
+                                 geometry=geometry)
+    res = build_session(app, "pallas", tool=oracle, workers=1).run()
     saved = oracle.flush()
     print(f"fleet-record: {len(oracle.store)} measured points "
           f"(device_kind={oracle.device_kind!r}) -> {saved} "
@@ -170,9 +174,12 @@ if __name__ == "__main__":
                          "(interpreter on CPU, compiled on TPU)")
     ap.add_argument("--backend", choices=["analytical", "pallas"],
                     default="analytical")
+    ap.add_argument("--app", choices=["fleet", "fleet-zamba2-7b"],
+                    default="fleet",
+                    help="the fleet geometry --record measures")
     args = ap.parse_args()
     if args.record:
-        raise SystemExit(record())
+        raise SystemExit(record(args.app))
     if args.smoke:
         raise SystemExit(smoke(args.backend))
     from run import Report          # harness report, standalone

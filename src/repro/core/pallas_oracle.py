@@ -154,7 +154,8 @@ class PallasKernelSpec:
     ``vmem_bytes``/``grid_steps`` are the kernel package's cost models
     (``(H, W, ports=, unrolls=) -> int``).  ``n_in``/``n_out`` are the
     VMEM blocks the kernel streams per grid step — the Eq. (1)
-    gamma_r/gamma_w analogues used for the state estimate.
+    gamma_r/gamma_w analogues used for the state estimate.  ``tiling``,
+    when given, names the tiling a knob point maps to, for its spans.
     """
 
     name: str
@@ -164,6 +165,9 @@ class PallasKernelSpec:
     grid_steps: Callable[..., int]
     n_in: int
     n_out: int
+    # (ports, unrolls) -> the tiling the knobs map to (block sizes,
+    # heads per step), carried on the point's ``pallas.lower`` span
+    tiling: Optional[Callable[[int, int], Dict[str, int]]] = None
 
     def divisible(self, ports: int, unrolls: int) -> bool:
         H, W = self.shape
@@ -608,6 +612,7 @@ class PallasOracle(OracleBatchMixin):
     # measurement
     # ------------------------------------------------------------------
     def _time_program(self, program: Any, args: Tuple[Any, ...],
+                      tiling: Optional[Dict[str, int]] = None,
                       **attrs: Any) -> Any:
         """Compile ``program`` for ``args``, warm it up, and return the
         best of ``reps`` timed launches (seconds), each ending in
@@ -617,7 +622,9 @@ class PallasOracle(OracleBatchMixin):
 
         Each stage is a span on :attr:`tracer` (``pallas.lower``,
         ``pallas.compile``, ``pallas.warmup``, ``pallas.reps``, carrying
-        ``attrs``), and the same clock reads feed :attr:`stats`.  A
+        ``attrs``), and the same clock reads feed :attr:`stats`;
+        ``pallas.lower`` also carries ``tiling`` and ``pallas.reps`` the
+        count of its ``launches``.  A
         program whose executable the point cache holds
         (:class:`~repro.launch.compile_cache.PointProgram`) lowers
         nothing: its ``pallas.lower`` reads ``point_cache="hit"`` and its
@@ -641,6 +648,8 @@ class PallasOracle(OracleBatchMixin):
                 lower.span.set(key, after[key] - before[key])
             point = point_outcome(before, after)
             lower.span.set("point_cache", point)
+            for key, value in (tiling or {}).items():
+                lower.span.set(key, value)
         if isinstance(lowered, str):
             return lowered
         with _Stage(self.tracer, "pallas.compile", lower.end,
@@ -670,6 +679,7 @@ class PallasOracle(OracleBatchMixin):
                 t, t0 = _CLOCK.now(), t
                 best = min(best, t - t0)
             reps.span.set("best_s", best)
+            reps.span.set("launches", self.reps)
         with self._lock:
             self.stats["lower_s"] += lower.seconds
             self.stats["compile_s"] += comp.end - lower.start
@@ -722,8 +732,10 @@ class PallasOracle(OracleBatchMixin):
                     wall = float(self.timer(spec.name, ports, unrolls,
                                             built))
                 else:
-                    wall = self._time_program(*built, component=spec.name,
-                                              ports=ports, unrolls=unrolls)
+                    wall = self._time_program(
+                        *built, component=spec.name, ports=ports,
+                        unrolls=unrolls,
+                        tiling=spec.tiling and spec.tiling(ports, unrolls))
                 with self._lock:
                     self.stats["refused" if isinstance(wall, str)
                                else "timed"] += 1

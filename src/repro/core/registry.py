@@ -99,6 +99,11 @@ class App:
     plm_tile_sizes_measured: Tuple[int, ...] = ()   # measured-drive axis
     # interpret-mode parity cases: (tile) -> [(name, fn, oracle, args)]
     parity_cases: Optional[Callable[..., List]] = None
+    # False: resolved by name alone (get_app), left out of list_apps()
+    # and so of every sweep over the registry (the scenario matrix, the
+    # parity sweeps, lint's default set) — for apps whose kernels run
+    # only at full size on a chip
+    listed: bool = True
 
     def available_tiles(self) -> Tuple[int, ...]:
         """The recorded tiles whose store files exist on disk."""
@@ -228,6 +233,7 @@ _BACKENDS: Dict[str, Backend] = {}
 _BUILTIN_APP_MODULES: Dict[str, str] = {
     "wami": "repro.apps.wami",
     "fleet": "repro.apps.fleet",
+    "fleet-zamba2-7b": "repro.apps.fleet",
 }
 
 
@@ -272,8 +278,9 @@ def get_backend(name: str) -> Backend:
 
 
 def list_apps() -> List[App]:
+    """The registered apps, by name, less those not ``listed``."""
     _ensure_builtin_apps()
-    return [_APPS[n] for n in sorted(_APPS)]
+    return [_APPS[n] for n in sorted(_APPS) if _APPS[n].listed]
 
 
 def list_backends() -> List[Backend]:

@@ -20,10 +20,12 @@ __all__ = ["mha", "mha_ref"]
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "softcap",
                                              "q_offset", "block_q",
-                                             "block_kv", "use_pallas",
-                                             "interpret"))
+                                             "block_kv", "block_h",
+                                             "vmem_limit_bytes",
+                                             "use_pallas", "interpret"))
 def mha(q, k, v, *, causal=True, window=0, softcap=0.0, q_offset=0,
-        block_q=128, block_kv=128, use_pallas=True, interpret=False):
+        block_q=128, block_kv=128, block_h=1, vmem_limit_bytes=None,
+        use_pallas=True, interpret=False):
     """q: (B, Sq, H, hd); k, v: (B, Skv, K, hd) -> (B, Sq, H, hd)."""
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
@@ -32,6 +34,8 @@ def mha(q, k, v, *, causal=True, window=0, softcap=0.0, q_offset=0,
         o = flash_attention(qt, kt, vt, causal=causal, window=window,
                             softcap=softcap, q_offset=q_offset,
                             block_q=block_q, block_kv=block_kv,
+                            block_h=block_h,
+                            vmem_limit_bytes=vmem_limit_bytes,
                             interpret=interpret)
     else:
         o = attention_ref(qt, kt, vt, causal=causal, window=window,
@@ -44,4 +48,6 @@ def mha_ref(q, k, v, **kw):
     kw.pop("interpret", None)
     kw.pop("block_q", None)
     kw.pop("block_kv", None)
+    kw.pop("block_h", None)
+    kw.pop("vmem_limit_bytes", None)
     return mha(q, k, v, use_pallas=False, **kw)

@@ -12,11 +12,15 @@ from .ref import ssd_ref
 __all__ = ["ssd", "ssd_oracle"]
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "use_pallas",
-                                             "interpret"))
-def ssd(x, dt, A, B, C, *, chunk=128, use_pallas=True, interpret=False):
+@functools.partial(jax.jit, static_argnames=("chunk", "block_h",
+                                             "vmem_limit_bytes",
+                                             "use_pallas", "interpret"))
+def ssd(x, dt, A, B, C, *, chunk=128, block_h=1, vmem_limit_bytes=None,
+        use_pallas=True, interpret=False):
     if use_pallas:
-        return ssd_scan(x, dt, A, B, C, chunk=chunk, interpret=interpret)
+        return ssd_scan(x, dt, A, B, C, chunk=chunk, block_h=block_h,
+                        vmem_limit_bytes=vmem_limit_bytes,
+                        interpret=interpret)
     return ssd_ref(x, dt, A, B, C)
 
 

@@ -97,6 +97,29 @@ def test_ssd_chunk_invariance():
         assert float(jnp.abs(o - outs[0]).max()) < 1e-4
 
 
+@pytest.mark.parametrize("block_h", [1, 2, 4])
+def test_head_blocks_match_the_oracles(block_h):
+    """Several heads per grid step: attention under GQA 2:1 (block_h
+    below, at and above the group) and the scan, whose heads share B/C."""
+    ks = jax.random.split(KEY, 8)
+    q = jax.random.normal(ks[0], (1, 128, 4, 32))
+    k = jax.random.normal(ks[1], (1, 128, 2, 32))
+    v = jax.random.normal(ks[2], (1, 128, 2, 32))
+    o = mha(q, k, v, block_q=64, block_kv=32, block_h=block_h,
+            use_pallas=True, interpret=True)
+    assert float(jnp.abs(o - mha_ref(q, k, v)).max()) < 2e-5
+    x = jax.random.normal(ks[3], (1, 64, 4, 16))
+    dt = jax.nn.softplus(jax.random.normal(ks[4], (1, 64, 4)) * 0.5)
+    A = -jnp.exp(jax.random.normal(ks[5], (4,)) * 0.3)
+    B = jax.random.normal(ks[6], (1, 64, 16)) * 0.3
+    C = jax.random.normal(ks[7], (1, 64, 16)) * 0.3
+    y1, h1 = ssd(x, dt, A, B, C, chunk=16, block_h=block_h,
+                 use_pallas=True, interpret=True)
+    y2, h2 = ssd_oracle(x, dt, A, B, C)
+    assert float(jnp.abs(y1 - y2).max()) < 1e-4
+    assert float(jnp.abs(h1 - h2).max()) < 1e-4
+
+
 # ----------------------------------------------------------------------
 # WAMI gradient (the COSMOS-knob kernel)
 # ----------------------------------------------------------------------
